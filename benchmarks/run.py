@@ -24,6 +24,8 @@ def main() -> None:
     ap.add_argument("--fast", action="store_true",
                     help="fewer rounds/seeds (CI budget)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     only = set(args.only.split(",")) if args.only else None
     os.makedirs(RESULTS_DIR, exist_ok=True)
     outputs = {}

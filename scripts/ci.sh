@@ -27,10 +27,14 @@
 # gate's wall-time is recorded in benchmarks/ANALYSIS_report.json. The
 # seeded-leak fixtures are then each asserted to FAIL the strict gate:
 # a verifier that stops flagging planted leaks is itself broken.
+# Everything here is a CPU tool: it runs under JAX_PLATFORMS=cpu, with
+# the Pallas kernels in interpret mode, on a machine with a TPU too (a
+# chip belongs to one process; the chip run is `python chip_smoke.py`).
 # Usage: scripts/ci.sh [extra pytest args]
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
+export JAX_PLATFORMS=cpu
 
 echo "== static analysis: contracts + lint + privacy taint (strict) =="
 python -m repro.analysis --strict --json benchmarks/ANALYSIS_report.json

@@ -129,20 +129,19 @@ def _eqn_loc(eqn) -> Tuple[str, int]:
     """Source location of an eqn, best-effort (file, line). Marker
     primitives bind inside this module's tree.map, so frames from the
     analysis layer itself are skipped — the finding points at the
-    protocol code that reached the sink."""
-    try:
-        from jax._src import source_info_util
-        fallback = None
-        for frame in source_info_util.user_frames(eqn.source_info):
-            loc = _rel(frame.file_name), int(frame.start_line)
-            if fallback is None:
-                fallback = loc
-            if not frame.file_name.endswith(_ANALYSIS_FILES):
-                return loc
-        if fallback is not None:
-            return fallback
-    except Exception:
-        pass
+    protocol code that reached the sink. An eqn without a traceback
+    has no frames and falls back to "<jaxpr>"; a change of JAX's
+    source-info API raises here rather than blanking every location."""
+    from jax._src import source_info_util
+    fallback = None
+    for frame in source_info_util.user_frames(eqn.source_info.traceback):
+        loc = _rel(frame.file_name), int(frame.start_line)
+        if fallback is None:
+            fallback = loc
+        if not frame.file_name.endswith(_ANALYSIS_FILES):
+            return loc
+    if fallback is not None:
+        return fallback
     return "<jaxpr>", 0
 
 

@@ -24,15 +24,17 @@ Each kernel-backed subsystem additionally carries a *tiling* field
                (`selection_vmem_bytes` / `exchange_vmem_bytes`)
                instead of an OOM at lowering time.
 
-This module deliberately imports only jax. `repro.core` modules import
-it directly; `repro.kernels.ops.resolve_backend` delegates here via a
-function-level import (`repro.core.__init__` pulls in the whole
-protocol, so a module-level import from the kernels package would be a
-cycle).
+This module deliberately imports only jax and the jax-only
+`repro.kernels` package init. `repro.core` modules import it directly;
+`repro.kernels.ops.resolve_backend` delegates here via a function-level
+import (`repro.core.__init__` pulls in the whole protocol, so a
+module-level import from the kernels package would be a cycle).
 """
 from __future__ import annotations
 
 import jax
+
+from repro.kernels import resolve_interpret
 
 BACKENDS = ("auto", "kernel", "oracle")
 TILINGS = ("auto", "oneshot", "tiled")
@@ -55,8 +57,10 @@ VMEM_BUDGET_BYTES = int(VMEM_LIMIT_BYTES * 0.75)
 
 
 def interpret() -> bool:
-    """Pallas kernels run in interpret mode everywhere but TPU."""
-    return jax.default_backend() != "tpu"
+    """Pallas kernels run in interpret mode everywhere but TPU (the
+    rule every kernel entry point applies when no `interpret` is
+    given)."""
+    return resolve_interpret()
 
 
 def _reject(field: str, value, accepted) -> ValueError:
@@ -102,14 +106,14 @@ def selection_tiled_vmem_bytes(bits_tot: int, *, block_m: int = 128,
     return unpacked + weights + scratch
 
 
-def exchange_vmem_bytes(n: int, r: int, c: int, *, block_m: int = 4) -> int:
+def exchange_vmem_bytes(n: int, r: int, c: int, *, block_m: int = 8) -> int:
     """One-shot `fused_exchange` working set per program: the
     (BM, N, R, C) neighbor-logit tile plus the (BM, R, C) own tile and
     the (BM, R, C) target output, f32."""
     return block_m * (n + 2) * r * c * 4
 
 
-def exchange_tiled_vmem_bytes(n: int, *, block_m: int = 4, block_r: int = 8,
+def exchange_tiled_vmem_bytes(n: int, *, block_m: int = 8, block_r: int = 8,
                               block_c: int = 512) -> int:
     """Streamed `fused_exchange_streamed` working set per program:
     O(tile) — the (BM, N, BR, BC) neighbor tile, the (BM, BR, BC) own

@@ -102,8 +102,7 @@ def all_in_one_exchange(own_logits, neighbor_logits, y_ref, sel_mask, fed,
         exchange_fn = (fused_exchange_streamed
                        if resolved_tiling == "tiled" else fused_exchange)
         out = exchange_fn(own_logits, neighbor_logits, y_ref, sel_mask,
-                          lsh_verification=fed.lsh_verification,
-                          interpret=backends.interpret())
+                          lsh_verification=fed.lsh_verification)
     elif resolved_tiling == "tiled":
         out = ref.streamed_exchange_ref(
             own_logits, neighbor_logits, y_ref, sel_mask,

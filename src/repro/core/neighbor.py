@@ -129,7 +129,7 @@ def select_partners(codes, scores, fed, *, rng=None, backend=None,
             ids, top_w = fused_select_ann(
                 codes, scores, cand.ids, bits=fed.lsh_bits,
                 gamma=fed.gamma, num_neighbors=n, use_lsh=fed.use_lsh,
-                use_rank=fed.use_rank, interpret=backends.interpret())
+                use_rank=fed.use_rank)
         else:
             ids, top_w = ref.ann_select_ref(
                 codes, scores, cand.ids, bits=fed.lsh_bits,
@@ -145,8 +145,7 @@ def select_partners(codes, scores, fed, *, rng=None, backend=None,
                      else fused_select)
         ids, top_w = select_fn(
             codes, scores, bits=fed.lsh_bits, gamma=fed.gamma,
-            num_neighbors=n, use_lsh=fed.use_lsh, use_rank=fed.use_rank,
-            interpret=backends.interpret())
+            num_neighbors=n, use_lsh=fed.use_lsh, use_rank=fed.use_rank)
     else:
         backends.resolve_tiling(tiling or fed.selection_tiling, 0)
         ids, top_w = ref.fused_select_ref(

@@ -9,11 +9,9 @@ kernel computes ONE shared neighbor log-softmax per client block and
 derives all three results from it while the (N, R, C) tile sits in
 VMEM (DESIGN.md §7):
 
-  * Eq. 3 CE losses l_ij via take_along_axis on the reference labels
-    (a one-hot compare+sum lowers more naturally on TPU but XLA's
-    fusion rewrites it away from the gathered value in the last ulp —
-    see the in-kernel comment; revisit if Mosaic rejects the gather on
-    compiled TPU);
+  * Eq. 3 CE losses l_ij, the label logit picked by an iota compare,
+    select and sum (Mosaic lowers no in-kernel gather; the sum has one
+    nonzero term, so the pick is exact);
   * §3.5 output-KL divergences against the client's own reference
     outputs, plus the upper-half keep filter. The rank is computed in
     counting form — rank(n) = #{m : kl_m < kl_n} + #{m < n : kl_m ==
@@ -27,19 +25,27 @@ VMEM (DESIGN.md §7):
 
 Bit-exactness (tests/test_exchange_pipeline.py): every derived value
 consumes the same floats in the same reduction order as the jnp oracle
-twin (`ref.all_in_one_exchange_ref`), so kernel and oracle agree
-bit-exactly in interpret mode; the oracle in turn is bit-identical to
-the unfused cross_entropy -> lsh_verification_mask ->
-aggregate_neighbor_outputs composition the round used to run.
+twin (`ref.all_in_one_exchange_ref`, jitted like the kernel), so kernel
+and oracle agree bit-exactly in interpret mode; the oracle in turn is
+bit-identical to the unfused cross_entropy -> lsh_verification_mask ->
+aggregate_neighbor_outputs composition the round used to run, compiled
+as one program. On the chip, Mosaic's exp/log and reduction order may
+differ from XLA's in the last ulps: compiled kernel and oracle agree
+to a tolerance, not bitwise.
 
-VMEM per program ~= BM_EXC * (N + 1) * R * C * 4 bytes for the logit
-tiles (at BM=4, N=16, R=64, C=1024 that is ~17 MB) — `fused_exchange`
-therefore caps near C ~ 10^3; vocab-scale reference sets need
+Mosaic layout: the client block is 8 rows (the f32 sublane tile), and
+every per-neighbor value (labels' CE, KL, the §3.5 mask, the selection
+mask) lives as (BM, N, 1, 1), so the neighbor axis never has to move
+onto lanes.
+
+VMEM per program ~= BM_EXC * (N + 2) * R * C * 4 bytes for the logit
+tiles (at BM=8, N=16, R=64, C=1024 that is ~38 MB) — `fused_exchange`
+therefore caps near C ~ 10^2-10^3; vocab-scale reference sets need
 `fused_exchange_streamed` (DESIGN.md §10): a (client-block, R-tile,
 C-tile) grid that streams (BM, N, BR, BC) blocks with a
 flash-attention-style online max / log-sum-exp for the shared neighbor
 log-softmax (see kernels/flash_attention.py). CE reduces to
-lse_nb - x_nb[y] (the label logit is gathered as C tiles stream by),
+lse_nb - x_nb[y] (the label logit is picked as C tiles stream by),
 the §3.5 output-KL to B/A - lse_own + lse_nb where A/B are online
 exp-weighted sums, and the per-row means accumulate across R tiles.
 Exactness contract (DESIGN.md §10): the online reductions REORDER the
@@ -66,8 +72,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.analysis.registry import kernel_contract
+from repro.kernels import resolve_interpret
 
-BM_EXC = 4          # client block per program
+BM_EXC = 8          # client block per program (f32 sublane width)
 BR_EXC = 8          # reference-row tile of the streamed kernel
 BC_EXC = 512        # class-column tile of the streamed kernel
 
@@ -75,55 +82,72 @@ BC_EXC = 512        # class-column tile of the streamed kernel
 def _upper_half_mask(kl_mean, sel_int):
     """§3.5 upper-half keep filter in counting-rank form, shared by the
     one-shot and streamed kernels: rank(n) = #{m : kl_m < kl_n} +
-    #{m < n : kl_m == kl_n} (the stable-argsort rank)."""
-    bm, n = kl_mean.shape
+    #{m < n : kl_m == kl_n} (the stable-argsort rank). The neighbor
+    axis is axis 1 of any (BM, N, ...) layout; the static loop over m
+    compares one neighbor slice against all, so no (N, N) relayout is
+    needed in Mosaic."""
     selm = sel_int != 0
     kls = jnp.where(selm, kl_mean, jnp.inf)
-    n_valid = jnp.sum(sel_int, axis=-1, keepdims=True)
-    keep = (n_valid + 1) // 2
-    lt = kls[:, :, None] < kls[:, None, :]
-    eq = kls[:, :, None] == kls[:, None, :]
-    a_idx = jax.lax.broadcasted_iota(jnp.int32, (bm, n, n), 1)
-    b_idx = jax.lax.broadcasted_iota(jnp.int32, (bm, n, n), 2)
-    rank_of = jnp.sum((lt | (eq & (a_idx < b_idx))).astype(jnp.int32),
-                      axis=1)                         # stable-sort rank
+    n = kls.shape[1]
+    idx = jax.lax.broadcasted_iota(jnp.int32, kls.shape, 1)
+    keep = (jnp.sum(sel_int, axis=1, keepdims=True) + 1) // 2
+    rank_of = jnp.zeros(kls.shape, jnp.int32)
+    for m in range(n):                                # static, N neighbors
+        km = kls[:, m:m + 1]
+        rank_of = rank_of + ((km < kls) | ((km == kls) & (m < idx))
+                             ).astype(jnp.int32)      # stable-sort rank
     return (rank_of < keep) & selm
 
 
 def _exchange_kernel(own_ref, nb_ref, y_ref, sel_ref,
                      l_ref, valid_ref, target_ref, *,
                      lsh_verification: bool):
+    # Layout: every per-neighbor value keeps (R, C)'s two minor axes as
+    # unit dims — (BM, N, 1, 1) — so the neighbor axis never moves onto
+    # lanes (Mosaic cannot reshape (BM, N) <-> (BM, N, 1, 1)).
     nb = nb_ref[...].astype(jnp.float32)              # (BM, N, R, C)
-    bm, n, r, c = nb.shape
     logp_nb = jax.nn.log_softmax(nb, axis=-1)         # ONE shared pass
-    selm = sel_ref[...] != 0                          # (BM, N)
 
-    # Eq. 3: CE of each neighbor's logits on the reference labels.
-    # take_along_axis, NOT a one-hot sum: XLA's fusion rewrites
-    # sum(where(onehot, logp, 0)) into a form that differs from the
-    # gathered value in the last ulp, which would break kernel/oracle
-    # bit-exactness (verified empirically; the two are identical
-    # un-jitted).
-    nll = -jnp.take_along_axis(logp_nb, y_ref[...][:, None, :, None],
-                               axis=-1)[..., 0]
-    l_ref[...] = jnp.mean(nll, axis=-1)               # (BM, N)
+    # Eq. 3: CE of each neighbor's logits on the reference labels; the
+    # label pick is an iota compare, select and sum (exact: one term)
+    cls = jax.lax.broadcasted_iota(jnp.int32, nb.shape, 3)
+    nll = -jnp.sum(jnp.where(cls == y_ref[...][:, None], logp_nb, 0.0),
+                   axis=-1, keepdims=True)            # (BM, N, R, 1)
+    l_ref[...] = jnp.mean(nll, axis=2, keepdims=True)
 
     # §3.5: output-KL upper-half filter over the selected slots
     if lsh_verification:
         logp_own = jax.nn.log_softmax(
             own_ref[...].astype(jnp.float32), axis=-1)  # (BM, R, C)
         kl = jnp.sum(jnp.exp(logp_own)[:, None]
-                     * (logp_own[:, None] - logp_nb), axis=-1)
-        valid = _upper_half_mask(jnp.mean(kl, axis=-1), sel_ref[...])
+                     * (logp_own[:, None] - logp_nb), axis=-1,
+                     keepdims=True)
+        valid = _upper_half_mask(jnp.mean(kl, axis=2, keepdims=True),
+                                 sel_ref[...])
     else:
-        valid = selm
+        valid = sel_ref[...] != 0
     valid_ref[...] = valid.astype(jnp.int32)
 
     # masked distillation-target mean (zeros fallback when none pass)
-    w = valid.astype(jnp.float32)
-    denom = jnp.maximum(jnp.sum(w, axis=-1), 1.0)
-    target_ref[...] = (jnp.einsum("bn,bnrc->brc", w, nb)
-                       / denom[:, None, None])
+    target_ref[...] = _masked_mean(valid.astype(jnp.float32), nb)
+
+
+def _over_rows(x, r: int):
+    """(..., 1, 1) -> (..., R, 1). Mosaic widens a value along sublanes
+    or along lanes, never both in one broadcast; the iota select keeps
+    the compiler from folding this sublane step into the lane
+    broadcast that follows. Exact: it selects x everywhere."""
+    shape = x.shape[:-2] + (r, 1)
+    rows = jax.lax.broadcasted_iota(jnp.int32, shape, x.ndim - 2)
+    return jnp.where(rows >= 0, x, 0.0)
+
+
+def _masked_mean(w, nb):
+    """Distillation-target mean over the neighbor axis: w (BM, N, 1, 1)
+    0/1 weights, nb (BM, N, R, C) -> (BM, R, C)."""
+    r = nb.shape[2]
+    denom = jnp.maximum(jnp.sum(w, axis=1), 1.0)      # (BM, 1, 1)
+    return jnp.sum(_over_rows(w, r) * nb, axis=1) / _over_rows(denom, r)
 
 
 # --- repro.analysis contract helpers (DESIGN.md §12) -----------------------
@@ -151,7 +175,8 @@ def _exchange_point_args(pt):
 @functools.partial(jax.jit, static_argnames=("lsh_verification",
                                              "interpret"))
 def fused_exchange(own_logits, neighbor_logits, y_ref, sel_mask, *,
-                   lsh_verification: bool = True, interpret: bool = True):
+                   lsh_verification: bool = True,
+                   interpret: bool | None = None):
     """Fused Eq. 3 + §3.5 + target mean. own_logits: (M, R, C);
     neighbor_logits: (M, N, R, C); y_ref: (M, R) int; sel_mask: (M, N)
     bool -> (l_ij (M, N) f32, valid (M, N) bool, target_ref (M, R, C)
@@ -163,8 +188,9 @@ def fused_exchange(own_logits, neighbor_logits, y_ref, sel_mask, *,
                     ((0, pm), (0, 0), (0, 0)))
     nb_p = jnp.pad(neighbor_logits.astype(jnp.float32),
                    ((0, pm), (0, 0), (0, 0), (0, 0)))
-    y_p = jnp.pad(y_ref.astype(jnp.int32), ((0, pm), (0, 0)))
-    sel_p = jnp.pad(sel_mask.astype(jnp.int32), ((0, pm), (0, 0)))
+    y_p = jnp.pad(y_ref.astype(jnp.int32), ((0, pm), (0, 0)))[..., None]
+    sel_p = jnp.pad(sel_mask.astype(jnp.int32),
+                    ((0, pm), (0, 0)))[..., None, None]
     mp = m + pm
     l_ij, valid, target = pl.pallas_call(
         functools.partial(_exchange_kernel,
@@ -173,23 +199,24 @@ def fused_exchange(own_logits, neighbor_logits, y_ref, sel_mask, *,
         in_specs=[
             pl.BlockSpec((BM_EXC, r, c), lambda i: (i, 0, 0)),
             pl.BlockSpec((BM_EXC, n, r, c), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((BM_EXC, r), lambda i: (i, 0)),
-            pl.BlockSpec((BM_EXC, n), lambda i: (i, 0)),
+            pl.BlockSpec((BM_EXC, r, 1), lambda i: (i, 0, 0)),
+            pl.BlockSpec((BM_EXC, n, 1, 1), lambda i: (i, 0, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((BM_EXC, n), lambda i: (i, 0)),
-            pl.BlockSpec((BM_EXC, n), lambda i: (i, 0)),
+            pl.BlockSpec((BM_EXC, n, 1, 1), lambda i: (i, 0, 0, 0)),
+            pl.BlockSpec((BM_EXC, n, 1, 1), lambda i: (i, 0, 0, 0)),
             pl.BlockSpec((BM_EXC, r, c), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((mp, n), jnp.float32),
-            jax.ShapeDtypeStruct((mp, n), jnp.int32),
+            jax.ShapeDtypeStruct((mp, n, 1, 1), jnp.float32),
+            jax.ShapeDtypeStruct((mp, n, 1, 1), jnp.int32),
             jax.ShapeDtypeStruct((mp, r, c), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(own_p, nb_p, y_p, sel_p)
-    valid = valid[:m].astype(bool)
-    return l_ij[:m], valid, target[:m], jnp.any(valid, axis=-1)
+    l_ij = l_ij[:m, :, 0, 0]
+    valid = valid[:m, :, 0, 0].astype(bool)
+    return l_ij, valid, target[:m], jnp.any(valid, axis=-1)
 
 
 # ---------------------------------------------------------------------------
@@ -241,7 +268,7 @@ def _streamed_stats_kernel(own_ref, nb_ref, y_ref, sel_ref,
                 + jnp.sum(po[:, None] * (xo[:, None] - xn), axis=-1))
     # Eq. 3 label-logit gather: the C tile holding y contributes x[y]
     # exactly once (raw logits, exact zeros elsewhere)
-    match = col[None, None, :] == y_ref[...][:, :, None]  # (BM, BR, BC)
+    match = col[None, None, :] == y_ref[...]          # (BM, BR, BC)
     g_nb[...] = g_nb[...] + jnp.sum(
         jnp.where(match[:, None], xn, 0.0), axis=-1)
     m_own[...] = mo_new
@@ -276,11 +303,8 @@ def _target_kernel(nb_ref, w_ref, t_ref):
     """Masked distillation-target mean over one (BM, N, BR, BC) tile.
     Stateless: the N-contraction is per output element, so R/C tiling
     does not change its value."""
-    w = w_ref[...].astype(jnp.float32)                # (BM, N)
-    denom = jnp.maximum(jnp.sum(w, axis=-1), 1.0)
-    t_ref[...] = (jnp.einsum("bn,bnrc->brc", w,
-                             nb_ref[...].astype(jnp.float32))
-                  / denom[:, None, None])
+    t_ref[...] = _masked_mean(w_ref[...].astype(jnp.float32),
+                              nb_ref[...].astype(jnp.float32))
 
 
 def streamed_tiles(r: int, c: int, block_r: int, block_c: int):
@@ -308,8 +332,9 @@ def streamed_tiles(r: int, c: int, block_r: int, block_c: int):
     "lsh_verification", "interpret", "block_m", "block_r", "block_c"))
 def fused_exchange_streamed(own_logits, neighbor_logits, y_ref, sel_mask,
                             *, lsh_verification: bool = True,
-                            interpret: bool = True, block_m: int = BM_EXC,
-                            block_r: int = BR_EXC, block_c: int = BC_EXC):
+                            interpret: bool | None = None,
+                            block_m: int = BM_EXC, block_r: int = BR_EXC,
+                            block_c: int = BC_EXC):
     """Streamed Eq. 3 + §3.5 + target mean (DESIGN.md §10): same
     contract as `fused_exchange`, but VMEM per program is
     O(BM * N * BR * BC) — R and C are bounded by HBM, not VMEM.
@@ -338,7 +363,7 @@ def fused_exchange_streamed(own_logits, neighbor_logits, y_ref, sel_mask,
             pl.BlockSpec((bm, br, bc), lambda i, ri, ci: (i, ri, ci)),
             pl.BlockSpec((bm, n, br, bc),
                          lambda i, ri, ci: (i, 0, ri, ci)),
-            pl.BlockSpec((bm, br), lambda i, ri, ci: (i, ri)),
+            pl.BlockSpec((bm, br, 1), lambda i, ri, ci: (i, ri, 0)),
             pl.BlockSpec((bm, n), lambda i, ri, ci: (i, 0)),
         ],
         out_specs=[
@@ -359,20 +384,20 @@ def fused_exchange_streamed(own_logits, neighbor_logits, y_ref, sel_mask,
             pltpu.VMEM((bm, br), jnp.float32),        # running max (own)
             pltpu.VMEM((bm, br), jnp.float32),        # running sum-exp (own)
         ],
-        interpret=interpret,
-    )(own_p, nb_p, y_p, sel_p)
+        interpret=resolve_interpret(interpret),
+    )(own_p, nb_p, y_p[..., None], sel_p)
     target = pl.pallas_call(
         _target_kernel,
         grid=(mp // bm, nr, nc),
         in_specs=[
             pl.BlockSpec((bm, n, br, bc),
                          lambda i, ri, ci: (i, 0, ri, ci)),
-            pl.BlockSpec((bm, n), lambda i, ri, ci: (i, 0)),
+            pl.BlockSpec((bm, n, 1, 1), lambda i, ri, ci: (i, 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, br, bc), lambda i, ri, ci: (i, ri, ci)),
         out_shape=jax.ShapeDtypeStruct((mp, r + pr, c + pc), jnp.float32),
-        interpret=interpret,
-    )(nb_p, valid)
+        interpret=resolve_interpret(interpret),
+    )(nb_p, valid[..., None, None])
     valid_b = valid[:m].astype(bool)
     return (l_ij[:m], valid_b, target[:m, :r, :c],
             jnp.any(valid_b, axis=-1))

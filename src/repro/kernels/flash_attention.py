@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.analysis.registry import kernel_contract
+from repro.kernels import resolve_interpret
 
 BQ = 256
 BK = 256
@@ -88,7 +89,7 @@ def _flash_point_args(pt):
 @functools.partial(jax.jit,
                    static_argnames=("causal", "scale", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, scale: float = 0.0,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q: (N, Sq, dh), k/v: (N, Sk, dh) -> (N, Sq, dh)."""
     n, sq, dh = q.shape
     sk = k.shape[1]
@@ -112,5 +113,5 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float = 0.0,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

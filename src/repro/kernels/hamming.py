@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.analysis.registry import kernel_contract
+from repro.kernels import resolve_interpret
 
 BM = 32
 BN = 128
@@ -49,7 +50,7 @@ def _hamming_kernel(a_ref, b_ref, out_ref):
         (jax.ShapeDtypeStruct((pt["m"], pt["w"]), jnp.uint32),
          jax.ShapeDtypeStruct((pt["n"], pt["w"]), jnp.uint32)), {}))
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def hamming_all_pairs(codes_a, codes_b, *, interpret: bool = True):
+def hamming_all_pairs(codes_a, codes_b, *, interpret: bool | None = None):
     """codes: (M, W) x (N, W) uint32 (M % BM == 0, N % BN == 0, caller
     pads) -> (M, N) int32 distances."""
     m, w = codes_a.shape
@@ -64,5 +65,5 @@ def hamming_all_pairs(codes_a, codes_b, *, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((BM, BN), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(codes_a, codes_b)

@@ -42,6 +42,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.analysis.registry import kernel_contract
+from repro.kernels import resolve_interpret
 
 CHUNK = 2048
 BLOCK_M = 8        # client rows per batched program (f32 sublane width)
@@ -65,7 +66,8 @@ def rademacher_block(i0, chunk, bits, seed):
     h = h * jnp.uint32(_K3)
     h = h ^ (h >> jnp.uint32(13))
     bit = (h >> jnp.uint32(9)) & jnp.uint32(1)
-    return 1.0 - 2.0 * bit.astype(jnp.float32)
+    # a select, not a cast: Mosaic has no uint32 -> f32 conversion
+    return jnp.where(bit != 0, -1.0, 1.0).astype(jnp.float32)
 
 
 def _lsh_kernel(seed_ref, x_ref, out_ref, *, bits: int):
@@ -89,7 +91,8 @@ def _lsh_kernel(seed_ref, x_ref, out_ref, *, bits: int):
         (jax.ShapeDtypeStruct((pt["p"],), jnp.float32),),
         dict(seed=7, bits=pt["bits"])))
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
-def lsh_project_sums(x, seed, *, bits: int = 256, interpret: bool = True):
+def lsh_project_sums(x, seed, *, bits: int = 256,
+                     interpret: bool | None = None):
     """x: (P,) f32 (P padded to CHUNK by the caller) -> (bits,) f32 sums."""
     assert x.ndim == 1 and x.shape[0] % CHUNK == 0, x.shape
     n_chunks = x.shape[0] // CHUNK
@@ -104,7 +107,7 @@ def lsh_project_sums(x, seed, *, bits: int = 256, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((1, bits), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, bits), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(seed_arr, x2)
     return out[0]
 
@@ -132,7 +135,7 @@ def _lsh_batched_kernel(seed_ref, x_ref, out_ref, *, bits: int):
         dict(seed=7, bits=pt["bits"])))
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
 def lsh_project_sums_batched(x, seed, *, bits: int = 256,
-                             interpret: bool = True):
+                             interpret: bool | None = None):
     """Batched Eq. (5) over the stacked client axis.
 
     x: (M, P) f32 with M % BLOCK_M == 0 and P % CHUNK == 0 (caller pads;
@@ -156,5 +159,5 @@ def lsh_project_sums_batched(x, seed, *, bits: int = 256,
         ],
         out_specs=pl.BlockSpec((BLOCK_M, bits), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, bits), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(seed_arr, x)

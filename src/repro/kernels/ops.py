@@ -1,9 +1,10 @@
 """Jitted public wrappers around the Pallas kernels.
 
-These handle padding/packing and backend selection (interpret=True on
-CPU, compiled on TPU) and expose pytree-level convenience APIs used by
-repro.core.lsh. The pure-jnp semantics live in ref.py; tests assert the
-kernel and oracle agree bit-exactly across shape/dtype sweeps.
+These handle padding/packing (each kernel compiles on TPU and runs in
+interpret mode elsewhere — `repro.kernels.resolve_interpret`) and
+expose pytree-level convenience APIs used by repro.core.lsh. The
+pure-jnp semantics live in ref.py; tests assert the kernel and oracle
+agree bit-exactly across shape/dtype sweeps.
 """
 from __future__ import annotations
 
@@ -18,11 +19,6 @@ from repro.kernels.hamming import BM, BN, hamming_all_pairs
 from repro.kernels.lsh_projection import (BLOCK_M, CHUNK,
                                           lsh_project_sums,
                                           lsh_project_sums_batched)
-
-
-def _interpret() -> bool:
-    from repro.core.backends import interpret  # see resolve_backend
-    return interpret()
 
 
 def resolve_backend(backend: str) -> str:
@@ -79,8 +75,7 @@ def batched_lsh_codes(flat2d, seed, *, bits: int = 256,
     if use_kernel:
         pm = (-m) % BLOCK_M
         x = jnp.pad(flat2d, ((0, pm), (0, 0)))
-        sums = lsh_project_sums_batched(x, seed, bits=bits,
-                                        interpret=_interpret())[:m]
+        sums = lsh_project_sums_batched(x, seed, bits=bits)[:m]
     else:
         sums = ref.lsh_project_sums_batched_ref(flat2d, seed, bits=bits)
     return pack_bits(sums)
@@ -90,7 +85,7 @@ def lsh_code(params, seed, *, bits: int = 256, use_kernel: bool = True):
     """WPFed Eq. (5): packed uint32 LSH code of a parameter pytree."""
     flat = flatten_params(params)
     if use_kernel:
-        sums = lsh_project_sums(flat, seed, bits=bits, interpret=_interpret())
+        sums = lsh_project_sums(flat, seed, bits=bits)
     else:
         sums = ref.lsh_project_sums_ref(flat, seed, bits=bits)
     return pack_bits(sums)
@@ -108,7 +103,7 @@ def hamming_matrix(codes, *, use_kernel: bool = True):
     pm = (-m) % max(BM, BN)
     pw = (-w) % 128
     padded = jnp.pad(codes, ((0, pm), (0, pw)))
-    d = hamming_all_pairs(padded, padded, interpret=_interpret())
+    d = hamming_all_pairs(padded, padded)
     return d[:m, :m]
 
 
@@ -124,8 +119,7 @@ def gqa_flash_attention(q, k, v, *, causal: bool = True,
     kx = jnp.repeat(jnp.moveaxis(k, 2, 1), g, axis=1).reshape(b * h, -1, dh)
     vx = jnp.repeat(jnp.moveaxis(v, 2, 1), g, axis=1).reshape(b * h, -1, dh)
     if use_kernel:
-        o = flash_attention(qk, kx, vx, causal=causal,
-                            interpret=_interpret())
+        o = flash_attention(qk, kx, vx, causal=causal)
     else:
         o = ref.flash_attention_ref(qk, kx, vx, causal=causal)
     return jnp.moveaxis(o.reshape(b, h, sq, dh), 1, 2)

@@ -1,6 +1,8 @@
 """Pure-jnp oracles for the Pallas kernels (bit-exact references)."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -106,6 +108,7 @@ def ann_select_ref(codes, scores, cand_ids, *, bits: int, gamma: float,
             top_w)
 
 
+@functools.partial(jax.jit, static_argnames=("lsh_verification",))
 def all_in_one_exchange_ref(own_logits, neighbor_logits, y_ref, sel_mask,
                             *, lsh_verification: bool = True):
     """Oracle for the fused exchange kernel (WPFed Eq. 3 + §3.5 + the
@@ -124,13 +127,19 @@ def all_in_one_exchange_ref(own_logits, neighbor_logits, y_ref, sel_mask,
     computing it once is exact, and the §3.5 rank is the stable-argsort
     rank in counting form (ties break ascending-index, matching
     jnp.argsort). Tested in tests/test_exchange_pipeline.py.
+
+    Jitted, like the kernel: XLA-CPU's log/exp round differently in a
+    fused program and op by op, so the twin is compared as one compiled
+    program. The label pick (iota compare, select, sum) and the target
+    sum are the kernel's own formulation.
     """
     own = own_logits.astype(jnp.float32)
     nb = neighbor_logits.astype(jnp.float32)
     logp_nb = jax.nn.log_softmax(nb, axis=-1)           # ONE shared pass
     # Eq. 3: per-neighbor CE on the reference labels
-    nll = -jnp.take_along_axis(
-        logp_nb, y_ref[:, None, :, None].astype(jnp.int32), axis=-1)[..., 0]
+    cls = jnp.arange(nb.shape[-1], dtype=jnp.int32)
+    nll = -jnp.sum(jnp.where(cls == y_ref[:, None, :, None].astype(jnp.int32),
+                             logp_nb, 0.0), axis=-1)
     l_ij = jnp.mean(nll, axis=-1)                       # (M, N)
     # §3.5: output-KL similarity, upper-half filter over selected slots
     if lsh_verification:
@@ -151,7 +160,8 @@ def all_in_one_exchange_ref(own_logits, neighbor_logits, y_ref, sel_mask,
     # masked distillation-target mean (zeros fallback when none pass)
     w = valid.astype(jnp.float32)
     denom = jnp.maximum(jnp.sum(w, axis=-1), 1.0)
-    target = jnp.einsum("mn,mnrc->mrc", w, nb) / denom[:, None, None]
+    target = (jnp.sum(w[:, :, None, None] * nb, axis=1)
+              / denom[:, None, None])
     has_target = jnp.sum(w, axis=-1) > 0
     return l_ij, valid, target, has_target
 

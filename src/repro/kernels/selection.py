@@ -29,11 +29,12 @@ the both-off random ablation needs an rng and stays outside the kernel —
 see core.neighbor.select_partners). Self-weights and padded columns are
 masked to -inf before selection.
 
-Top-N: N iterations of (max, argmax, knock out) over the row block.
-argmax takes the first maximum, which reproduces jax.lax.top_k's
-tie-breaking (ascending index among equal values), so selected ids
-match the unfused path exactly as long as N <= M-1 (always true: the
-protocol clamps N to M-1, and every non-self weight is finite).
+Top-N: N iterations of (max, first max, knock out) over the row block
+(`_knockout_topn`, in the gather-free form Mosaic lowers). Taking the
+first maximum reproduces jax.lax.top_k's tie-breaking (ascending index
+among equal values), so selected ids match the unfused path exactly as
+long as N <= M-1 (always true: the protocol clamps N to M-1, and every
+non-self weight is finite).
 
 The packed word axis is NOT padded: the arrays the kernel computes on
 are the unpacked (rows, W*32) bit matrices, whose last dim is already
@@ -45,11 +46,11 @@ at M ~ 10^4 clients.
 `fused_select_tiled` removes that ceiling (DESIGN.md §10): a second
 grid axis streams (BM, BK) *column tiles* of the same ±1 Gram matrix
 while a VMEM scratch carries a per-row running top-N. Pass 1 is the
-streamed merge-by-knockout: each tile's weights are concatenated with
-the running (vals, ids) candidates and N knockout iterations keep the
-best N. Because earlier tiles hold strictly smaller global column
-indices, putting the running candidates FIRST in the concatenation
-preserves `lax.top_k`'s first-max (ascending-index) tie-breaking
+streamed merge-by-knockout: each tile's weights are read as one
+concatenation with the running (vals, ids) candidates and N knockout
+iterations keep the best N. Because earlier tiles hold strictly smaller
+global column indices, putting the running candidates FIRST in that
+order preserves `lax.top_k`'s first-max (ascending-index) tie-breaking
 exactly; weights are the same exact-integer distances fed to the same
 elementwise exp, so ids AND weights are bit-exact against
 `ref.fused_select_ref` and the one-shot kernel at every M. Pass 2
@@ -66,6 +67,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.analysis.registry import kernel_contract
+from repro.kernels import resolve_interpret
 
 BM_SEL = 8          # row block (f32 sublane width)
 BM_SEL_TILED = 128  # row block of the column-tiled kernel
@@ -76,12 +78,19 @@ BK_ANN = 256        # candidate tile of the ANN kernel (VMEM ~2 MB)
 
 def unpack_pm1(words):
     """(R, W) packed uint32 -> (R, W*32) f32 in {-1, +1} (bit=1 -> +1).
-    Pure shifts + masks; lowers identically on TPU and in interpret
-    mode."""
+
+    Stays 2-D with the bit axis on lanes: column k reads word k // 32
+    (a W-way select of lane-broadcast word columns) and shifts by
+    k % 32; the ±1 comes from a select, since Mosaic has no uint32 ->
+    f32 cast and no lane-merging reshape. Lowers identically on TPU and
+    in interpret mode."""
     r, w = words.shape
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, (r, w, 32), 2)
-    bits01 = ((words[:, :, None] >> shifts) & jnp.uint32(1))
-    return (2.0 * bits01.astype(jnp.float32) - 1.0).reshape(r, w * 32)
+    col = jax.lax.broadcasted_iota(jnp.int32, (r, w * 32), 1)
+    word = jnp.zeros((r, w * 32), jnp.uint32)
+    for i in range(w):                                # static, W = bits/32
+        word = jnp.where(col // 32 == i, words[:, i:i + 1], word)
+    bit = (word >> (col % 32).astype(jnp.uint32)) & jnp.uint32(1)
+    return jnp.where(bit != 0, 1.0, -1.0).astype(jnp.float32)
 
 
 def _eq8_weights(d, s, row_ids, col_ids, *, bits: int, gamma: float,
@@ -121,18 +130,40 @@ def _gram_weights(a_words, b_words, s_row, row0, col0, *, bits: int,
     return w, col
 
 
-def _knockout_topn(cand_v, cand_i, nsel: int):
-    """N iterations of (max, first-argmax, knock out) over the
-    candidate axis — reproduces lax.top_k's ascending-index
-    tie-breaking as long as cand_i is ascending within equal values."""
-    pos = jax.lax.broadcasted_iota(jnp.int32, cand_v.shape, 1)
-    ids, vals = [], []
-    for _ in range(nsel):                             # static unroll
-        vals.append(jnp.max(cand_v, axis=1))
-        p = jnp.argmax(cand_v, axis=1)
-        ids.append(jnp.take_along_axis(cand_i, p[:, None], axis=1)[:, 0])
-        cand_v = jnp.where(pos == p[:, None], -jnp.inf, cand_v)
-    return jnp.stack(vals, axis=1), jnp.stack(ids, axis=1).astype(jnp.int32)
+def _knockout_topn(parts, nsel: int):
+    """N iterations of (max, first max, knock out) over `parts`, a
+    sequence of (vals, ids) candidate blocks read as ONE concatenation
+    along the lane axis in the given order — reproduces lax.top_k's
+    ascending-position tie-breaking over that concatenation.
+
+    Written for Mosaic, which lowers no in-kernel gather, argmax or
+    unaligned lane concatenation: the first max is a min over masked
+    lane positions, the id pick an iota-compare select and sum, and the
+    (BM, N) outputs fill lane by lane. Every step is exact."""
+    vals = [v for v, _ in parts]
+    pos = [jax.lax.broadcasted_iota(jnp.int32, v.shape, 1) for v in vals]
+    bm = vals[0].shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bm, nsel), 1)
+    top_v = jnp.zeros((bm, nsel), jnp.float32)
+    top_i = jnp.zeros((bm, nsel), jnp.int32)
+    for t in range(nsel):                             # static unroll
+        best = functools.reduce(jnp.maximum, [
+            jnp.max(v, axis=1, keepdims=True) for v in vals])
+        taken = jnp.zeros((bm, 1), jnp.bool_)
+        pick = jnp.zeros((bm, 1), jnp.int32)
+        for k, (_, ids) in enumerate(parts):
+            width = vals[k].shape[1]
+            first = jnp.min(jnp.where(vals[k] == best, pos[k], width),
+                            axis=1, keepdims=True)
+            hit = (first < width) & ~taken            # earliest part wins
+            at = (pos[k] == first) & hit
+            pick = pick + jnp.sum(jnp.where(at, ids, 0), axis=1,
+                                  keepdims=True)
+            vals[k] = jnp.where(at, -jnp.inf, vals[k])
+            taken = taken | hit
+        top_v = jnp.where(lane == t, best, top_v)
+        top_i = jnp.where(lane == t, pick, top_i)
+    return top_v, top_i
 
 
 def _select_kernel(a_ref, b_ref, s_ref, ids_ref, w_ref, *, bits: int,
@@ -142,7 +173,7 @@ def _select_kernel(a_ref, b_ref, s_ref, ids_ref, w_ref, *, bits: int,
     w, col = _gram_weights(a_ref[...], b_ref[...], s_ref[...], row0, 0,
                            bits=bits, gamma=gamma, m_real=m_real,
                            use_lsh=use_lsh, use_rank=use_rank)
-    vals, ids = _knockout_topn(w, col, nsel)
+    vals, ids = _knockout_topn([(w, col)], nsel)
     ids_ref[...] = ids
     w_ref[...] = vals
 
@@ -179,7 +210,7 @@ def _select_vmem_extra(site, pt):
     "bits", "gamma", "num_neighbors", "use_lsh", "use_rank", "interpret"))
 def fused_select(codes, scores, *, bits: int, gamma: float,
                  num_neighbors: int, use_lsh: bool = True,
-                 use_rank: bool = True, interpret: bool = True):
+                 use_rank: bool = True, interpret: bool | None = None):
     """Fused Eq. 6-8 + top-N. codes: (M, W) uint32, scores: (M,) f32
     -> (ids (M, N) int32, top_w (M, N) f32). Pads M to the row-block
     grid; padded rows are discarded and padded columns never win
@@ -210,7 +241,7 @@ def fused_select(codes, scores, *, bits: int, gamma: float,
             jax.ShapeDtypeStruct((mp, nsel), jnp.int32),
             jax.ShapeDtypeStruct((mp, nsel), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(padded, padded, scores_p)
     return ids[:m], top_w[:m]
 
@@ -233,11 +264,10 @@ def _select_tiled_kernel(a_ref, b_ref, s_ref, ids_ref, w_ref,
                            use_rank=use_rank)
     # Merge-by-knockout: running candidates FIRST — they come from
     # earlier column tiles, so their global ids are strictly smaller
-    # and first-max argmax keeps lax.top_k's ascending-index
-    # tie-breaking across tile boundaries.
-    cand_v = jnp.concatenate([vals_scr[...], w], axis=1)
-    cand_i = jnp.concatenate([ids_scr[...], col], axis=1)
-    vals, ids = _knockout_topn(cand_v, cand_i, nsel)
+    # and the first max keeps lax.top_k's ascending-index tie-breaking
+    # across tile boundaries.
+    vals, ids = _knockout_topn([(vals_scr[...], ids_scr[...]), (w, col)],
+                               nsel)
     vals_scr[...] = vals
     ids_scr[...] = ids
 
@@ -270,7 +300,7 @@ def _select_tiled_vmem_extra(site, pt):
     "block_m", "block_k"))
 def fused_select_tiled(codes, scores, *, bits: int, gamma: float,
                        num_neighbors: int, use_lsh: bool = True,
-                       use_rank: bool = True, interpret: bool = True,
+                       use_rank: bool = True, interpret: bool | None = None,
                        block_m: int = BM_SEL_TILED, block_k: int = BK_SEL):
     """Column-tiled two-pass fused selection (DESIGN.md §10): same
     contract as `fused_select` — (ids (M, N) int32, top_w (M, N) f32),
@@ -315,7 +345,7 @@ def fused_select_tiled(codes, scores, *, bits: int, gamma: float,
             pltpu.VMEM((bm, nsel), jnp.float32),
             pltpu.VMEM((bm, nsel), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rows, cols, scores_p)
     return ids[:m], top_w[:m]
 
@@ -337,25 +367,22 @@ def _select_ann_kernel(a_ref, c_ref, ci_ref, cs_ref, ids_ref, w_ref,
     w_words = cw.shape[-1]
     uc = unpack_pm1(cw.reshape(bm * bk, w_words)).reshape(bm, bk, -1)
     bits_tot = ua.shape[1]
-    # per-row batched Gram: each row block has its OWN candidate codes,
-    # so the contraction batches over the row axis instead of sharing
-    # one ±1 matrix. Distances stay exact integers in f32 (§4).
-    gram = jax.lax.dot_general(
-        ua, uc, (((1,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)           # (BM, BK)
+    # per-row Gram: each row has its OWN candidate codes, so this is a
+    # multiply and lane sum (Mosaic lowers no matrix-vector batched
+    # dot). Distances stay exact integers in f32 (§4).
+    gram = jnp.sum(ua[:, None, :] * uc, axis=-1)      # (BM, BK)
     d = (float(bits_tot) - gram) * 0.5
     row = row0 + jax.lax.broadcasted_iota(jnp.int32, (bm, bk), 0)
     col = ci_ref[...]                                 # gathered global ids
     w = _eq8_weights(d, cs_ref[...], row, col, bits=bits, gamma=gamma,
                      m_real=m_real, use_lsh=use_lsh, use_rank=use_rank)
     # §10 knockout merge, running candidates FIRST: earlier candidate
-    # tiles hold earlier candidate positions, so first-max argmax
+    # tiles hold earlier candidate positions, so the first max
     # reproduces lax.top_k's tie-breaking over the full candidate axis
     # (and, in the one-bucket fallback where candidates are ascending
     # client ids, over the full client axis — the bit-exact case).
-    cand_v = jnp.concatenate([vals_scr[...], w], axis=1)
-    cand_i = jnp.concatenate([ids_scr[...], col], axis=1)
-    vals, ids = _knockout_topn(cand_v, cand_i, nsel)
+    vals, ids = _knockout_topn([(vals_scr[...], ids_scr[...]), (w, col)],
+                               nsel)
     vals_scr[...] = vals
     ids_scr[...] = ids
 
@@ -397,7 +424,7 @@ def _select_ann_vmem_extra(site, pt):
     "block_m", "block_k"))
 def fused_select_ann(codes, scores, cand_ids, *, bits: int, gamma: float,
                      num_neighbors: int, use_lsh: bool = True,
-                     use_rank: bool = True, interpret: bool = True,
+                     use_rank: bool = True, interpret: bool | None = None,
                      block_m: int = BM_ANN, block_k: int = BK_ANN):
     """ANN candidate selection (DESIGN.md §11): exact Eq. 6-8 weights
     computed ONLY on `cand_ids` (the (M, K) per-client candidate sets
@@ -464,7 +491,7 @@ def fused_select_ann(codes, scores, cand_ids, *, bits: int, gamma: float,
             pltpu.VMEM((bm, nsel), jnp.float32),
             pltpu.VMEM((bm, nsel), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(rows, cand_codes, cand_p, cand_scores)
     ids, top_w = ids[:m], top_w[:m]
     # no-finite-candidate slots: pin the id to 0 (matches the twin's

@@ -287,8 +287,6 @@ def dryrun_fed_round(num_clients: int = 256, arch: str = "phi3-medium-14b",
         compiled = lowered.compile()
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, list):                  # older jax returns [dict]
-        cost = cost[0] if cost else {}
     print(json.dumps({
         "fed_round_clients": m,
         "client_arch": cfg.name,
@@ -305,6 +303,8 @@ def dryrun_fed_round(num_clients: int = 256, arch: str = "phi3-medium-14b",
 
 
 def main(argv=None):
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="mnist",
                     choices=["mnist", "aecg", "seeg"])

@@ -284,8 +284,7 @@ def apply_moe_sharded(cfg: ModelConfig, p, x):
                / slots}
         return out.reshape(b, s, d).astype(x_l.dtype), aux
 
-    from repro.compat import shard_map
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(wspec, xspec),
         out_specs=(xspec, {"load_balance": P(), "dropped_frac": P()}),
         check_vma=False)(p, x)

@@ -1,47 +1,9 @@
 import functools
-import sys
-import types
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-
-# ---------------------------------------------------------------------------
-# Optional-dependency shim: hypothesis is not installable in the offline
-# environment. Several modules do `from hypothesis import given, settings,
-# strategies as st` at import time; without this shim the whole module
-# fails collection. The stub skips only the @given-decorated tests —
-# plain tests in the same module still run.
-# ---------------------------------------------------------------------------
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    _hyp = types.ModuleType("hypothesis")
-    _st = types.ModuleType("hypothesis.strategies")
-
-    def _given(*_a, **_k):
-        def deco(fn):
-            return pytest.mark.skip(
-                reason="hypothesis not installed (offline environment)")(fn)
-        return deco
-
-    def _settings(*_a, **_k):
-        def deco(fn):
-            return fn
-        return deco
-
-    def _strategy_stub(*_a, **_k):
-        return None
-
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.strategies = _st
-    for _name in ("integers", "floats", "booleans", "lists", "tuples",
-                  "sampled_from", "text", "composite", "just", "one_of"):
-        setattr(_st, _name, _strategy_stub)
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
 
 from repro.configs.paper_models import ClientModelConfig, FedConfig
 from repro.models import apply_client_model, init_client_model

@@ -89,3 +89,27 @@ def test_input_specs_shapes():
     vlm = get_config("llama-3.2-vision-90b")
     sp_v = dr.input_specs(vlm, SHAPES["prefill_32k"])
     assert sp_v["vision"].shape == (32, vlm.vision_tokens, vlm.vision_dim)
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(from_env, monkeypatch, tmp_path):
+    """Entry points keep the compile cache where JAX_COMPILATION_CACHE_DIR
+    says and set nothing else; without it, in `.jax_cache/` at the
+    checkout root, which .gitignore lists."""
+    from repro.launch.compile_cache import CHECKOUT_ROOT, use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert use_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            path = use_compile_cache()
+            assert path == str(CHECKOUT_ROOT / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == path
+            assert (CHECKOUT_ROOT / "src" / "repro").is_dir()
+            ignored = (CHECKOUT_ROOT / ".gitignore").read_text().split()
+            assert ".jax_cache/" in ignored
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -61,13 +61,19 @@ def test_exchange_kernel_matches_oracle(m, n, r, c, lsh_verification):
 @pytest.mark.parametrize("m,n,r,c", [(6, 3, 12, 3), (7, 5, 8, 10)])
 def test_exchange_oracle_matches_unfused_composition(m, n, r, c):
     """The oracle is bit-identical to the three scattered calls the
-    round ran before the fusion (acceptance: round metrics unchanged)."""
+    round ran before the fusion (acceptance: round metrics unchanged).
+    Both sides are compiled programs, as in the jitted round."""
     own, nb, y, sel = _inputs(m, n, r, c, seed=m + n)
-    l_legacy = jax.vmap(lambda yl, yy: jax.vmap(
-        lambda l: distill.cross_entropy(l, yy))(yl))(nb, y)
-    v_legacy = jax.vmap(verify.lsh_verification_mask)(own, nb, sel)
-    t_legacy, h_legacy = jax.vmap(distill.aggregate_neighbor_outputs)(
-        nb, v_legacy)
+
+    @jax.jit
+    def legacy(own, nb, y, sel):
+        l_legacy = jax.vmap(lambda yl, yy: jax.vmap(
+            lambda l: distill.cross_entropy(l, yy))(yl))(nb, y)
+        v_legacy = jax.vmap(verify.lsh_verification_mask)(own, nb, sel)
+        return (l_legacy, v_legacy,
+                *jax.vmap(distill.aggregate_neighbor_outputs)(nb, v_legacy))
+
+    l_legacy, v_legacy, t_legacy, h_legacy = legacy(own, nb, y, sel)
     l_o, v_o, t_o, h_o = ref.all_in_one_exchange_ref(own, nb, y, sel)
     assert bool(jnp.all(l_legacy == l_o))
     assert bool(jnp.all(v_legacy == v_o))
@@ -262,9 +268,12 @@ def test_ref_mode_rejects_unknown(tiny_fed):
 # ---------------------------------------------------------------------------
 # launcher wiring
 # ---------------------------------------------------------------------------
-def test_dryrun_threads_clients_and_ref_mode(monkeypatch):
+def test_dryrun_threads_clients_and_ref_mode(monkeypatch, tmp_path):
     """Regression: `--dryrun` used to silently ignore `--clients`."""
     from repro.launch import fed as fed_launch
+    # main() places the compile cache; with the variable set it leaves
+    # this process's JAX config alone
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     calls = {}
 
     def fake_dryrun(num_clients=256, arch="phi3-medium-14b",

@@ -72,15 +72,14 @@ def test_commitment_distinguishes_orderings(seed, n):
 @given(st.integers(0, 2**16))
 def test_sharded_lsh_equals_full_projection(seed):
     """Beyond-paper sharded LSH: sum of per-shard partial projections ==
-    projection of the full vector (linearity), asserted via the
-    shard_map helper on a 1-device mesh."""
-    from repro.compat import shard_map
+    projection of the full vector (linearity), asserted under
+    jax.shard_map on a 1-device mesh."""
     from repro.kernels.ref import lsh_project_sums_ref
     key = jax.random.PRNGKey(seed)
     n = 4096
     x = jax.random.normal(key, (n,))
     mesh = jax.make_mesh((1,), ("model",))
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda v: lsh.sharded_lsh_code(v, 7, 128, "model"),
         mesh=mesh, in_specs=jax.sharding.PartitionSpec("model"),
         out_specs=jax.sharding.PartitionSpec(), check_vma=False)

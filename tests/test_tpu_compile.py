@@ -1,0 +1,119 @@
+"""The round-path Pallas kernels compile for a TPU v5e at real shapes.
+
+Nothing runs: each case lowers a kernel entry point with
+`interpret=False` for a described (not attached) v5e chip and compiles
+it with the TPU compiler installed here, so a block shape, cast, gather
+or layout that Mosaic refuses fails this file instead of a chip run.
+Shapes are the federation's own: mnist-cnn's parameter count for LSH,
+selection on each side of the one-shot/tiled and exact/ANN switches,
+and the paper's exchange shape (M=16 after padding, N=9, R=64, C=10).
+
+The topology is described inside a module fixture, never at import:
+only one process at a time may load the TPU compiler's library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+BITS = 256
+WORDS = BITS // 32
+NEIGHBORS = 12
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+
+
+@pytest.fixture(scope="module")
+def sds(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+
+
+def _mnist_cnn_padded_p():
+    from repro.configs.paper_models import mnist_cnn
+    from repro.kernels.lsh_projection import CHUNK
+    from repro.models import init_client_model
+    shapes = jax.eval_shape(lambda k: init_client_model(mnist_cnn(), k),
+                            jax.random.PRNGKey(0))
+    p = sum(leaf.size for leaf in jax.tree.leaves(shapes))
+    return p + (-p) % CHUNK
+
+
+def _lsh(sds):
+    from repro.kernels.lsh_projection import lsh_project_sums_batched
+    p = _mnist_cnn_padded_p()
+    assert p > 4e5, p                       # mnist-cnn's real width
+    return lsh_project_sums_batched.lower(
+        sds((16, p), jnp.float32), sds((), jnp.uint32), bits=BITS,
+        interpret=False)
+
+
+def _select(name, m):
+    """fused_select / fused_select_tiled / fused_select_ann at M=m; the
+    ANN kernel gets the candidate width FedConfig's defaults give."""
+    def lower(sds):
+        from repro.configs.paper_models import FedConfig
+        from repro.core import ann
+        from repro.kernels import selection
+        args = [sds((m, WORDS), jnp.uint32), sds((m,), jnp.float32)]
+        if name == "fused_select_ann":
+            fed = FedConfig()
+            k = ann.candidate_count(m, fed.ann_prefix_bits, fed.ann_probes,
+                                    NEIGHBORS, BITS)
+            args.append(sds((m, k), jnp.int32))
+        return getattr(selection, name).lower(
+            *args, bits=BITS, gamma=1.0, num_neighbors=NEIGHBORS,
+            interpret=False)
+    return lower
+
+
+def _exchange(name, m, n, r, c):
+    def lower(sds):
+        from repro.kernels import exchange
+        return getattr(exchange, name).lower(
+            sds((m, r, c), jnp.float32), sds((m, n, r, c), jnp.float32),
+            sds((m, r), jnp.int32), sds((m, n), jnp.bool_),
+            interpret=False)
+    return lower
+
+
+CASES = {
+    "lsh_batched-mnist_cnn-m16": _lsh,
+    "select-m16": _select("fused_select", 16),
+    "select-m4096": _select("fused_select", 4096),
+    "select_tiled-m16384": _select("fused_select_tiled", 16384),
+    "select_ann-m16384": _select("fused_select_ann", 16384),
+    "exchange-m16-n9-r64-c10": _exchange("fused_exchange", 16, 9, 64, 10),
+    "exchange_streamed-m8-n8-r64-c8192":
+        _exchange("fused_exchange_streamed", 8, 8, 64, 8192),
+}
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_kernel_compiles_for_v5e(case, sds):
+    compiled = CASES[case](sds).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, case
+    if case.startswith("exchange_streamed"):
+        # the stats kernel and the target kernel
+        assert text.count("tpu_custom_call") >= 2, case
