@@ -1,0 +1,9 @@
+"""Device-busy time per period program of the ops in the program's
+`announce` scope, the next announcement (LSH codes with the projection
+kernel, rankings, commitments), in ms. Ops are mapped to phases by the
+program's `repro.spans.op_scopes()`; times come from the device trace."""
+import progspans
+
+
+def read(ctx):
+    return progspans.read_phase(ctx, "announce")

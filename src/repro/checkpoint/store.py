@@ -12,6 +12,8 @@ from typing import Any, List, Optional
 import jax
 import numpy as np
 
+from repro import spans
+
 _SEP = "/"
 
 
@@ -20,6 +22,7 @@ def _flatten(tree) -> dict:
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
         key = _SEP.join(_seg(p) for p in path)
         arr = np.asarray(leaf)  # analysis: host-ok — checkpointing IS the device->host pull
+        spans.count(spans.HOST_PULLS)
         if arr.dtype.name == "bfloat16":     # npz can't serialize ml_dtypes
             arr = arr.astype(np.float32)     # (restore casts back per `like`)
         out[key] = arr

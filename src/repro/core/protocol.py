@@ -16,7 +16,9 @@ phases instead of forking a monolith (DESIGN.md §7):
 `wpfed_program` composes them into a `core.rounds.RoundProgram`: the
 global round (all four phases — one federation iteration for all M
 clients) plus the gossip epoch (exchange + update against the cached
-`SelectResult`, DESIGN.md §8). `make_wpfed_round` is the classic sync
+`SelectResult`, DESIGN.md §8). Each phase call runs under a
+`jax.named_scope` of its short name (`repro.spans.PHASES`), which
+names its ops in the compiled program and nothing else. `make_wpfed_round` is the classic sync
 adapter over that program. Client models are homogeneous pytrees
 stacked on a leading (M,) axis; `launch/fed.py` shards that axis
 across the mesh for TPU-scale runs.
@@ -311,12 +313,16 @@ def wpfed_program(apply_fn: Callable, optimizer: Optimizer,
                      ) -> Tuple[FedState, SelectResult, Dict]:
         rng, rng_sel, rng_upd = jax.random.split(state.rng, 3)
 
-        sel = select_phase(state, fed, rng=rng_sel)
-        exch = exchange_phase(apply_fn, fed, state.params, data, sel)
-        params, opt_state, train_metrics = update_phase(
-            apply_fn, optimizer, fed, state.params, state.opt_state,
-            data, exch, rng_upd)
-        ann = announce_phase(fed, params, sel, exch, state.round)
+        with jax.named_scope("select"):
+            sel = select_phase(state, fed, rng=rng_sel)
+        with jax.named_scope("exchange"):
+            exch = exchange_phase(apply_fn, fed, state.params, data, sel)
+        with jax.named_scope("update"):
+            params, opt_state, train_metrics = update_phase(
+                apply_fn, optimizer, fed, state.params, state.opt_state,
+                data, exch, rng_upd)
+        with jax.named_scope("announce"):
+            ann = announce_phase(fed, params, sel, exch, state.round)
 
         metrics = _round_metrics(sel, exch, train_metrics, state.round)
         new_state = FedState(params, opt_state, ann.codes, ann.rankings,
@@ -327,10 +333,12 @@ def wpfed_program(apply_fn: Callable, optimizer: Optimizer,
                      sel: SelectResult
                      ) -> Tuple[FedState, SelectResult, Dict]:
         rng, rng_upd = jax.random.split(state.rng)
-        exch = exchange_phase(apply_fn, fed, state.params, data, sel)
-        params, opt_state, train_metrics = update_phase(
-            apply_fn, optimizer, fed, state.params, state.opt_state,
-            data, exch, rng_upd)
+        with jax.named_scope("exchange"):
+            exch = exchange_phase(apply_fn, fed, state.params, data, sel)
+        with jax.named_scope("update"):
+            params, opt_state, train_metrics = update_phase(
+                apply_fn, optimizer, fed, state.params, state.opt_state,
+                data, exch, rng_upd)
         metrics = _round_metrics(sel, exch, train_metrics, state.round)
         new_state = state._replace(params=params, opt_state=opt_state,
                                    rng=rng, round=state.round + 1)

@@ -39,14 +39,17 @@ here, and `make_program` resolves them via function-level imports
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import io_callback
 
+from repro import spans
 from repro.analysis.privacy import declassifier, sink
+
+# counted in the period program's Python body, so only when it traces
+TRACES = "period.traces"
 
 
 class RoundProgram(NamedTuple):
@@ -161,8 +164,8 @@ def make_segment_fn(program: RoundProgram, length: int, *,
     segment_fn(state, data) -> (state, metrics) with every metric
     stacked on a leading (length,) round axis.
 
-    `eval_fn(state, data) -> dict` (jittable) is merged into each
-    round's metrics — this keeps per-round evaluation inside the
+    `eval_fn(state, data) -> dict` (jittable, run under the named scope
+    `evaluate`) is merged into each round's metrics — this keeps per-round evaluation inside the
     compiled segment instead of forcing a host sync per round.
 
     `metrics_tap(scalars: dict) -> None` (host function) additionally
@@ -177,10 +180,15 @@ def make_segment_fn(program: RoundProgram, length: int, *,
             f"program {program.name!r} has no gossip_round; "
             "only Schedule(reselect_every=1) can run it")
 
+    def scoped_eval(state, data):
+        with jax.named_scope("evaluate"):
+            return eval_fn(state, data)
+
     def seg_fn(state, data):
+        spans.count(TRACES)
         state, cache, m0 = program.global_round(state, data)
         if eval_fn is not None:
-            m0 = {**m0, **eval_fn(state, data)}
+            m0 = {**m0, **scoped_eval(state, data)}
         if metrics_tap is not None:
             _stream_metrics(metrics_tap, m0)
         if length == 1:
@@ -193,7 +201,7 @@ def make_segment_fn(program: RoundProgram, length: int, *,
             st, ca = carry
             st, ca, m = program.gossip_round(st, data, ca)
             if eval_fn is not None:
-                m = {**m, **eval_fn(st, data)}
+                m = {**m, **scoped_eval(st, data)}
             if metrics_tap is not None:
                 _stream_metrics(metrics_tap, m)
             return (st, ca), m
@@ -221,9 +229,26 @@ def extract_history(metrics, r0, length):  # analysis: host-ok (see below)
             if getattr(v, "ndim", None) == 1:  # per-round scalar
                 is_int = jnp.issubdtype(v.dtype, jnp.integer)
                 entry[k] = int(v[i]) if is_int else float(v[i])
+        spans.count(spans.HOST_PULLS, len(entry))
         entry["round"] = r0 + i
         history.append(entry)
     return history
+
+
+def run_period_program(seg_fn, state, data):
+    """One call of a jitted period program, timed by the host period
+    loops: `period.dispatch` up to the call's return (recorded as
+    `period.compile` when the call traced the program, which is then
+    registered with `spans` for `op_scopes`) and `period.wait` until
+    its metrics are on hand. Returns (state, metrics, seconds of both)."""
+    with spans.span("period.dispatch") as call:
+        out_state, metrics = seg_fn(state, data)
+        if call.counts.get(TRACES):
+            call.name = "period.compile"
+            spans.register_program(seg_fn, state, data)
+    with spans.span("period.wait") as wait:
+        jax.block_until_ready(metrics)
+    return out_state, metrics, call.seconds + wait.seconds
 
 
 def run_rounds(program: RoundProgram, state, data, *, rounds: int,
@@ -244,27 +269,33 @@ def run_rounds(program: RoundProgram, state, data, *, rounds: int,
     Returns (final_state, history): one dict per round holding every
     scalar metric (plus `eval_fn` outputs) as a Python number and the
     absolute "round" index.
+
+    Each period is recorded (`repro.spans`) as a `period` span holding
+    `period.dispatch` or `period.compile`, `period.wait`,
+    `period.on_reselect`, `period.history` and `period.log`.
     """
     schedule = schedule or Schedule()
     seg_fns: Dict[int, Callable] = {}
     history: List[Dict[str, Any]] = []
-    for r0, length in schedule.segments(rounds):
-        if length not in seg_fns:
-            seg_fns[length] = jax.jit(
-                make_segment_fn(program, length, eval_fn=eval_fn))
-        t0 = time.time()
-        state, metrics = seg_fns[length](state, data)
-        jax.block_until_ready(metrics)
-        dt = time.time() - t0
-        if on_reselect is not None:
-            on_reselect(r0, state)
-        history.extend(extract_history(metrics, r0, length))
-        if log is not None:
-            last = history[-1]
-            parts = [f"{k} {last[k]:.4f}" for k in ("acc", "mean_loss")
-                     if k in last]
-            log(f"round {last['round']:3d} " + " ".join(parts)
-                + f" ({dt:.1f}s/{length}r)")
+    for period, (r0, length) in enumerate(schedule.segments(rounds)):
+        with spans.span("period", period=period):
+            if length not in seg_fns:
+                seg_fns[length] = jax.jit(
+                    make_segment_fn(program, length, eval_fn=eval_fn))
+            state, metrics, dt = run_period_program(seg_fns[length],
+                                                    state, data)
+            if on_reselect is not None:
+                with spans.span("period.on_reselect"):
+                    on_reselect(r0, state)
+            with spans.span("period.history"):
+                history.extend(extract_history(metrics, r0, length))
+            if log is not None:
+                with spans.span("period.log"):
+                    last = history[-1]
+                    parts = [f"{k} {last[k]:.4f}"
+                             for k in ("acc", "mean_loss") if k in last]
+                    log(f"round {last['round']:3d} " + " ".join(parts)
+                        + f" ({dt:.1f}s/{length}r)")
     return state, history
 
 

@@ -212,6 +212,7 @@ def fused_exchange(own_logits, neighbor_logits, y_ref, sel_mask, *,
             jax.ShapeDtypeStruct((mp, n, 1, 1), jnp.int32),
             jax.ShapeDtypeStruct((mp, r, c), jnp.float32),
         ],
+        name="fused_exchange",
         interpret=resolve_interpret(interpret),
     )(own_p, nb_p, y_p, sel_p)
     l_ij = l_ij[:m, :, 0, 0]

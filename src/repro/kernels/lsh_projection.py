@@ -159,5 +159,6 @@ def lsh_project_sums_batched(x, seed, *, bits: int = 256,
         ],
         out_specs=pl.BlockSpec((BLOCK_M, bits), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, bits), jnp.float32),
+        name="lsh_project_sums_batched",
         interpret=resolve_interpret(interpret),
     )(seed_arr, x)

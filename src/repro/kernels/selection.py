@@ -241,6 +241,7 @@ def fused_select(codes, scores, *, bits: int, gamma: float,
             jax.ShapeDtypeStruct((mp, nsel), jnp.int32),
             jax.ShapeDtypeStruct((mp, nsel), jnp.float32),
         ],
+        name="fused_select",
         interpret=resolve_interpret(interpret),
     )(padded, padded, scores_p)
     return ids[:m], top_w[:m]
@@ -345,6 +346,7 @@ def fused_select_tiled(codes, scores, *, bits: int, gamma: float,
             pltpu.VMEM((bm, nsel), jnp.float32),
             pltpu.VMEM((bm, nsel), jnp.int32),
         ],
+        name="fused_select_tiled",
         interpret=resolve_interpret(interpret),
     )(rows, cols, scores_p)
     return ids[:m], top_w[:m]
@@ -491,6 +493,7 @@ def fused_select_ann(codes, scores, cand_ids, *, bits: int, gamma: float,
             pltpu.VMEM((bm, nsel), jnp.float32),
             pltpu.VMEM((bm, nsel), jnp.int32),
         ],
+        name="fused_select_ann",
         interpret=resolve_interpret(interpret),
     )(rows, cand_codes, cand_p, cand_scores)
     ids, top_w = ids[:m], top_w[:m]

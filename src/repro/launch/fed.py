@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.configs.paper_models import (FedConfig, PAPER_FED_OPTIMA,
                                         aecg_tcn, mnist_cnn,
                                         recommended_dedupe, seeg_tcn)
@@ -50,14 +51,16 @@ def chain_publisher(chain: Blockchain, num_clients: int):
     def publish(round_idx: int, state) -> None:  # analysis: host-ok
         # intentional device->host pull, once per reselection period:
         # the ledger records announcements, not device arrays (§8)
-        codes = np.asarray(state.codes)
-        rankings = np.asarray(state.rankings)
-        ann = {i: {"lsh": lsh_code_hex(codes[i]),
-                   "commit": sha256_commit(rankings[i])}
-               for i in range(num_clients)}
-        reveals = {i: [int(x) for x in rankings[i]]
+        with spans.span("ledger.publish"):
+            codes = np.asarray(state.codes)
+            rankings = np.asarray(state.rankings)
+            spans.count(spans.HOST_PULLS, 2)
+            ann = {i: {"lsh": lsh_code_hex(codes[i]),
+                       "commit": sha256_commit(rankings[i])}
                    for i in range(num_clients)}
-        chain.publish_round(round_idx + 1, ann, reveals=reveals)
+            reveals = {i: [int(x) for x in rankings[i]]
+                       for i in range(num_clients)}
+            chain.publish_round(round_idx + 1, ann, reveals=reveals)
 
     return publish
 

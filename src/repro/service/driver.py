@@ -45,7 +45,6 @@ land on the period's history entries.
 from __future__ import annotations
 
 import os
-import time
 import warnings
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -53,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.analysis.privacy import sink
 from repro.checkpoint import store
 from repro.configs.paper_models import FedConfig
@@ -60,7 +60,8 @@ from repro.core.chain import Blockchain, save_chain
 from repro.core.faults import FaultPlan, fault_scalars
 from repro.core.protocol import (FedState, _round_metrics, announce_phase,
                                  exchange_phase, select_phase, update_phase)
-from repro.core.rounds import RoundProgram, extract_history, make_segment_fn
+from repro.core.rounds import (RoundProgram, extract_history,
+                               make_segment_fn, run_period_program)
 from repro.service.membership import (ChurnEvent, ServiceConfig,
                                       ServiceState, apply_events,
                                       mask_stragglers, merge_delivery,
@@ -128,15 +129,19 @@ def service_program(apply_fn: Callable, optimizer, fed: FedConfig,
                      ) -> Tuple[ServiceState, Any, Dict]:
         st = state.fed
         rng, rng_sel, rng_upd = jax.random.split(st.rng, 3)
-        sel = select_phase(
-            st, fed, rng=rng_sel, active=state.active,
-            score_scale=staleness_discount(state.code_age,
-                                           svc.staleness_lambda))
-        exch = exchange_phase(apply_fn, fed, st.params, data, sel)
-        params, opt_state, train_metrics = update_phase(
-            apply_fn, optimizer, fed, st.params, st.opt_state, data,
-            exch, rng_upd, participate=state.active)
-        ann = announce_phase(fed, params, sel, exch, st.round)
+        with jax.named_scope("select"):
+            sel = select_phase(
+                st, fed, rng=rng_sel, active=state.active,
+                score_scale=staleness_discount(state.code_age,
+                                               svc.staleness_lambda))
+        with jax.named_scope("exchange"):
+            exch = exchange_phase(apply_fn, fed, st.params, data, sel)
+        with jax.named_scope("update"):
+            params, opt_state, train_metrics = update_phase(
+                apply_fn, optimizer, fed, st.params, st.opt_state, data,
+                exch, rng_upd, participate=state.active)
+        with jax.named_scope("announce"):
+            ann = announce_phase(fed, params, sel, exch, st.round)
         a = state.active
         # these merged fields are what transport.collect reads onto the
         # host ledger and what checkpoints as chain.json — the service's
@@ -161,10 +166,12 @@ def service_program(apply_fn: Callable, optimizer, fed: FedConfig,
         # advanced past the period's global round)
         epoch = st.round - state.period_start - 1
         part = participation_mask(state, epoch)
-        exch = exchange_phase(apply_fn, fed, st.params, data, sel)
-        params, opt_state, train_metrics = update_phase(
-            apply_fn, optimizer, fed, st.params, st.opt_state, data,
-            exch, rng_upd, participate=part)
+        with jax.named_scope("exchange"):
+            exch = exchange_phase(apply_fn, fed, st.params, data, sel)
+        with jax.named_scope("update"):
+            params, opt_state, train_metrics = update_phase(
+                apply_fn, optimizer, fed, st.params, st.opt_state, data,
+                exch, rng_upd, participate=part)
         metrics = _service_metrics(sel, exch, train_metrics, state, part)
         new_state = state._replace(fed=st._replace(
             params=params, opt_state=opt_state, rng=rng,
@@ -280,6 +287,11 @@ def run_service(apply_fn: Callable, optimizer, fed: FedConfig,
     resume that lands on the crash period replays it instead of dying
     in a loop.
 
+    Each period is recorded (`repro.spans`) as a `period` span holding
+    `period.events`, `period.dispatch` or `period.compile`,
+    `period.wait`, the transport's `ledger.*` spans, `period.history`,
+    `period.checkpoint` and `period.log`.
+
     Restart recipe: rebuild (fed, svc, state-template, data, events)
     from the same configuration, then
     `state, chain, p0 = resume_service(ckpt_dir, template)` and call
@@ -310,64 +322,70 @@ def run_service(apply_fn: Callable, optimizer, fed: FedConfig,
                                      metrics_tap=tap))
     history: List[Dict] = []
     for period in range(start_period, periods):
-        state = apply_events(state, events, period)
-        base_active = state.active
-        pf = transport.period_faults(period, fed.num_clients)
-        scalars = None
-        if pf is not None:
-            announcing = np.asarray(base_active, bool)  # analysis: host-ok — membership mask pull for host-side fault bookkeeping
-            scalars = fault_scalars(pf, announcing)
-            fault_cell.clear()
-            fault_cell.update(scalars)
-            stragglers = transport.straggler_mask(period, announcing)
-            if stragglers.any():
-                # degraded round: proceed on partial announcements by
-                # the same masking churn uses (bit-identical to those
-                # clients leaving for one period)
-                state = mask_stragglers(state, stragglers)
-            pre = (state.fed.codes, state.fed.rankings,
-                   state.fed.commitments, state.code_age)
-        seg_active = state.active
-        t0 = time.time()
-        state, metrics = seg_fn(state, data)
-        jax.block_until_ready(metrics)
-        dt = time.time() - t0
-        if pf is not None and pf.crash and period != start_period:
-            raise CrashInjected(period)
-        r0 = period * length
-        if pf is not None:
-            state = state._replace(active=base_active)
+        with spans.span("period", period=period):
+            with spans.span("period.events"):
+                state = apply_events(state, events, period)
+                base_active = state.active
+                pf = transport.period_faults(period, fed.num_clients)
+                scalars = None
+                if pf is not None:
+                    announcing = np.asarray(base_active, bool)  # analysis: host-ok — membership mask pull for host-side fault bookkeeping
+                    spans.count(spans.HOST_PULLS)
+                    scalars = fault_scalars(pf, announcing)
+                    fault_cell.clear()
+                    fault_cell.update(scalars)
+                    stragglers = transport.straggler_mask(period,
+                                                          announcing)
+                    if stragglers.any():
+                        # degraded round: proceed on partial
+                        # announcements by the same masking churn uses
+                        # (bit-identical to those clients leaving for
+                        # one period)
+                        state = mask_stragglers(state, stragglers)
+                    pre = (state.fed.codes, state.fed.rankings,
+                           state.fed.commitments, state.code_age)
+            seg_active = state.active
+            state, metrics, dt = run_period_program(seg_fn, state, data)
+            if pf is not None and pf.crash and period != start_period:
+                raise CrashInjected(period)
+            r0 = period * length
+            if pf is not None:
+                state = state._replace(active=base_active)
+            # the transport pulls the announcing mask with the
+            # announcements
             ann, reveals, failed, delayed = transport.collect(
-                period, np.asarray(seg_active, bool), state)  # analysis: host-ok — announcement pull routes through the transport
-            if failed.any() or delayed.any():
-                state = merge_delivery(state, *pre, failed=failed,
-                                       delayed=delayed)
-        else:
-            ann, reveals, _, _ = transport.collect(
-                period, np.asarray(seg_active, bool), state)  # analysis: host-ok — announcement pull routes through the transport
-        transport.publish(period, r0, ann, reveals)
-        transport.fetch(period, r0)  # read-back verification
-        entries = extract_history(metrics, r0, length)
-        if scalars is not None:
-            entries[-1].update(scalars)
-        history.extend(entries)
-        if ckpt_dir is not None and \
-                (period + 1 - start_period) % svc.checkpoint_every == 0:
-            checkpoint_service(ckpt_dir, period, state, chain,
-                               keep_last_k=svc.keep_last_k)
-            if transport.plan is not None and \
-                    transport.plan.fork_at == period:
-                # fault injection: a competing rolled-back ledger view
-                # appears next to chain.json — resume must arbitrate
-                write_fork_view(ckpt_dir, rollback_view(chain, 1))
-        if log is not None:
-            last = history[-1]
-            parts = [f"{k} {last[k]:.4f}" for k in ("acc", "mean_loss")
-                     if k in last]
-            degraded = " DEGRADED" if scalars and \
-                scalars.get("degraded_round") else ""
-            log(f"period {period:3d} (rounds {r0}..{r0 + length - 1}) "
-                + " ".join(parts)
-                + f" active {last['active_frac']:.2f}"
-                + f" ({dt:.1f}s){degraded}")
+                period, seg_active, state)
+            if pf is not None and (failed.any() or delayed.any()):
+                with spans.span("period.events"):
+                    state = merge_delivery(state, *pre, failed=failed,
+                                           delayed=delayed)
+            transport.publish(period, r0, ann, reveals)
+            transport.fetch(period, r0)  # read-back verification
+            with spans.span("period.history"):
+                entries = extract_history(metrics, r0, length)
+            if scalars is not None:
+                entries[-1].update(scalars)
+            history.extend(entries)
+            if ckpt_dir is not None and \
+                    (period + 1 - start_period) % svc.checkpoint_every == 0:
+                with spans.span("period.checkpoint"):
+                    checkpoint_service(ckpt_dir, period, state, chain,
+                                       keep_last_k=svc.keep_last_k)
+                    if transport.plan is not None and \
+                            transport.plan.fork_at == period:
+                        # fault injection: a competing rolled-back
+                        # ledger view appears next to chain.json —
+                        # resume must arbitrate
+                        write_fork_view(ckpt_dir, rollback_view(chain, 1))
+            if log is not None:
+                with spans.span("period.log"):
+                    last = history[-1]
+                    parts = [f"{k} {last[k]:.4f}"
+                             for k in ("acc", "mean_loss") if k in last]
+                    degraded = " DEGRADED" if scalars and \
+                        scalars.get("degraded_round") else ""
+                    log(f"period {period:3d} (rounds {r0}..{r0 + length - 1}) "
+                        + " ".join(parts)
+                        + f" active {last['active_frac']:.2f}"
+                        + f" ({dt:.1f}s){degraded}")
     return state, chain, history

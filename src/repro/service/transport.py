@@ -44,6 +44,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro import spans
 from repro.core.chain import (Block, Blockchain, load_chain, lsh_code_hex,
                               save_chain, sha256_commit)
 from repro.core.faults import (FaultPlan, FaultTrace, PeriodFaults,
@@ -173,47 +174,49 @@ class BulletinTransport:
             weight sees `code_age >= 1`.
         Duplicate deliveries are byte-identical and dedupe to one
         entry (counted in the trace, no state effect)."""
-        announcing = np.asarray(announcing, bool)
-        codes = np.asarray(state.fed.codes)
-        rankings = np.asarray(state.fed.rankings)
-        m = announcing.shape[0]
-        pf = self.period_faults(period, m)
-        failed = np.zeros(m, bool)
-        delayed = np.zeros(m, bool)
-        announcements: Dict[int, Dict[str, str]] = {}
-        reveals: Dict[int, List[int]] = {}
-        for i in range(m):
-            if not announcing[i]:
-                continue
-            entry = {"lsh": lsh_code_hex(codes[i]),
-                     "commit": sha256_commit(rankings[i])}
-            entry["sum"] = announcement_checksum(entry)
-            if pf is not None:
-                if pf.drop[i]:
-                    failed[i] = True
-                    self.trace.record(period, "drop", i)
+        with spans.span("ledger.collect"):
+            announcing = np.asarray(announcing, bool)
+            codes = np.asarray(state.fed.codes)
+            rankings = np.asarray(state.fed.rankings)
+            spans.count(spans.HOST_PULLS, 3)     # mask, codes, rankings
+            m = announcing.shape[0]
+            pf = self.period_faults(period, m)
+            failed = np.zeros(m, bool)
+            delayed = np.zeros(m, bool)
+            announcements: Dict[int, Dict[str, str]] = {}
+            reveals: Dict[int, List[int]] = {}
+            for i in range(m):
+                if not announcing[i]:
                     continue
-                if pf.corrupt[i]:
-                    wire = dict(entry)
-                    wire["lsh"] = _corrupt_hex(
-                        wire["lsh"], fault_u01(self.plan.seed, "corrupt",
-                                               period, client=i, attempt=1))
-                    if announcement_checksum(wire) != wire["sum"]:
-                        # board-side rejection: the damaged bytes never
-                        # enter the ledger
+                entry = {"lsh": lsh_code_hex(codes[i]),
+                         "commit": sha256_commit(rankings[i])}
+                entry["sum"] = announcement_checksum(entry)
+                if pf is not None:
+                    if pf.drop[i]:
                         failed[i] = True
-                        self.trace.record(period, "corrupt", i)
+                        self.trace.record(period, "drop", i)
                         continue
-                    entry = wire  # (unreachable for a 1-nibble flip)
-                if pf.delay[i]:
-                    delayed[i] = True
-                    self.trace.record(period, "delay", i)
-                if pf.duplicate[i]:
-                    # the second, byte-identical copy dedupes to nothing
-                    self.trace.record(period, "duplicate", i)
-            announcements[i] = entry
-            reveals[i] = [int(x) for x in rankings[i]]
-        return announcements, reveals, failed, delayed
+                    if pf.corrupt[i]:
+                        wire = dict(entry)
+                        wire["lsh"] = _corrupt_hex(wire["lsh"], fault_u01(
+                            self.plan.seed, "corrupt", period, client=i,
+                            attempt=1))
+                        if announcement_checksum(wire) != wire["sum"]:
+                            # board-side rejection: the damaged bytes never
+                            # enter the ledger
+                            failed[i] = True
+                            self.trace.record(period, "corrupt", i)
+                            continue
+                        entry = wire  # (unreachable for a 1-nibble flip)
+                    if pf.delay[i]:
+                        delayed[i] = True
+                        self.trace.record(period, "delay", i)
+                    if pf.duplicate[i]:
+                        # the second, byte-identical copy dedupes to nothing
+                        self.trace.record(period, "duplicate", i)
+                announcements[i] = entry
+                reveals[i] = [int(x) for x in rankings[i]]
+            return announcements, reveals, failed, delayed
 
     def _with_retry(self, period: int, kind: str, stream: int,
                     fn: Callable[[], Any], what: str) -> Any:
@@ -239,23 +242,25 @@ class BulletinTransport:
         """Publish one period's block, idempotently (a replayed period
         after crash-restart finds its block already on chain and reuses
         it) and under bounded retry."""
-        existing = self.chain.round_block(round_idx)
-        if existing is not None:
-            return existing
-        return self._with_retry(
-            period, "publish_fail", 0,
-            lambda: self.chain.publish_round(round_idx, announcements,
-                                             reveals=reveals),
-            what=f"publish of round {round_idx}")
+        with spans.span("ledger.publish"):
+            existing = self.chain.round_block(round_idx)
+            if existing is not None:
+                return existing
+            return self._with_retry(
+                period, "publish_fail", 0,
+                lambda: self.chain.publish_round(round_idx, announcements,
+                                                 reveals=reveals),
+                what=f"publish of round {round_idx}")
 
     def fetch(self, period: int, round_idx: int) -> Block:
         """Read-back verification: re-fetch the just-published block
         (under retry) so a publish that claimed success but didn't land
         is caught the same period, not at resume."""
-        blk = self._with_retry(
-            period, "fetch_fail", 1,
-            lambda: self.chain.round_block(round_idx),
-            what=f"fetch of round {round_idx}")
+        with spans.span("ledger.fetch"):
+            blk = self._with_retry(
+                period, "fetch_fail", 1,
+                lambda: self.chain.round_block(round_idx),
+                what=f"fetch of round {round_idx}")
         if blk is None:
             raise TransportError(
                 f"round {round_idx} missing from the ledger on "

@@ -12,6 +12,7 @@ The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU compiler's library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -117,3 +118,39 @@ def test_kernel_compiles_for_v5e(case, sds):
     if case.startswith("exchange_streamed"):
         # the stats kernel and the target kernel
         assert text.count("tpu_custom_call") >= 2, case
+
+
+# the kernels' custom calls are named for their entry points: the
+# per-layer metrics of a profile find them by these prefixes
+KERNEL_NAMES = {"lsh_batched-mnist_cnn-m16": "lsh_project_sums_batched",
+                "exchange-m16-n9-r64-c10": "fused_exchange",
+                "select-m16": "fused_select"}
+CUSTOM_CALL = re.compile(
+    r"^\s*(?:ROOT )?%([\w.-]+) = .* custom-call\(.*"
+    r'custom_call_target="tpu_custom_call"', re.M)
+
+
+@pytest.mark.parametrize("case", list(KERNEL_NAMES))
+def test_kernel_instruction_names(case, sds):
+    names = CUSTOM_CALL.findall(CASES[case](sds).compile().as_text())
+    assert names and all(n.startswith(KERNEL_NAMES[case]) for n in names), \
+        names
+
+
+def test_pallas_name_sets_the_instruction_name(sds):
+    """`name=` of a pallas_call, not the jitted function around it, is
+    what the compiled custom call is named after."""
+    from jax.experimental import pallas as pl
+
+    def double(x_ref, o_ref):
+        o_ref[...] = x_ref[...] * 2
+
+    @jax.jit
+    def wrapper(x):
+        return pl.pallas_call(double, name="kernel_name",
+                              out_shape=jax.ShapeDtypeStruct(x.shape,
+                                                             x.dtype))(x)
+
+    text = wrapper.lower(sds((8, 128), jnp.float32)).compile().as_text()
+    names = CUSTOM_CALL.findall(text)
+    assert len(names) == 1 and names[0].startswith("kernel_name"), names
