@@ -43,6 +43,7 @@ from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import io_callback
 
 from repro import spans
@@ -221,15 +222,24 @@ def extract_history(metrics, r0, length):  # analysis: host-ok (see below)
     round (scalar metrics only, plus the absolute "round" index).
     Intentional host extraction: callers run it once per reselection
     period, after `jax.block_until_ready` (run_rounds here, the
-    continuous service driver in `repro.service.driver`)."""
+    continuous service driver in `repro.service.driver`).
+
+    The per-round scalars (the 1-D leaves) reach the host in one
+    batched `jax.device_get`, one `host_pulls` per array; the other
+    metrics (neighbor ids, masks, per-client vectors) stay on the
+    device. Integer dtypes become `int`, every other dtype `float`."""
+    stacked = {k: v for k, v in metrics.items()
+               if getattr(v, "ndim", None) == 1}  # per-round scalars
+    pulled = jax.device_get(stacked)
+    spans.count(spans.HOST_PULLS, len(pulled))
+    cols = []
+    for k in stacked:  # the metrics' own order (device_get sorts keys)
+        v = np.asarray(pulled[k])
+        cast = int if np.issubdtype(v.dtype, np.integer) else float
+        cols.append((k, cast, v))
     history: List[Dict[str, Any]] = []
     for i in range(length):
-        entry: Dict[str, Any] = {}
-        for k, v in metrics.items():
-            if getattr(v, "ndim", None) == 1:  # per-round scalar
-                is_int = jnp.issubdtype(v.dtype, jnp.integer)
-                entry[k] = int(v[i]) if is_int else float(v[i])
-        spans.count(spans.HOST_PULLS, len(entry))
+        entry: Dict[str, Any] = {k: cast(v[i]) for k, cast, v in cols}
         entry["round"] = r0 + i
         history.append(entry)
     return history

@@ -6,8 +6,11 @@ period loops record with it.
     attach to the innermost open span;
   * `run_rounds` records one `period` span per period with its
     children in order, `period.compile` once per segment length, and
-    `host_pulls` = history scalars plus the publisher's two arrays;
-  * `run_service` records `period.checkpoint` every `checkpoint_every`;
+    `host_pulls` = the history's arrays (one per metric, whatever the
+    period's length) plus the publisher's two;
+  * `run_service` records `period.checkpoint` every `checkpoint_every`
+    and counts one pull per history array, per transported array and
+    per checkpointed leaf;
   * under a CPU profiler trace every recorded span is on the host
     timeline under its name, with the recorder's duration;
   * `op_scopes()` maps the period program's ops to all five phases
@@ -134,12 +137,13 @@ def test_run_rounds_records_each_period_and_its_children(fed4):
     publish = [s for s in recorded if s["name"] == "ledger.publish"]
     assert len(publish) == 3 and all(
         s["counts"] == {spans.HOST_PULLS: 2} for s in publish)
-    scalars = sum(len(entry) for entry in history)
-    assert scalars == 3 * 8             # 7 round metrics and the accuracy
-    assert _counter(spans.HOST_PULLS) - pulls == scalars + 2 * 3
+    # one array of length 1 per metric: 7 round metrics and the accuracy
+    arrays = 8
+    assert all(len(entry) == arrays for entry in history)
+    assert _counter(spans.HOST_PULLS) - pulls == 3 * (arrays + 2)
     per_period = [sum(s["counts"].get(spans.HOST_PULLS, 0)
                       for s in _under(recorded, p)) for p in periods]
-    assert per_period == [8 + 2] * 3
+    assert per_period == [arrays + 2] * 3
 
 
 def test_compile_is_recorded_once_per_segment_length(fed4):
@@ -154,6 +158,9 @@ def test_compile_is_recorded_once_per_segment_length(fed4):
     assert _counter("period.traces") - traces == 2
     assert all(s["counts"] == {"period.traces": 1}
                for s in recorded if s["name"] == "period.compile")
+    # the history pulls 8 arrays a period, of 3, 3 and 1 rounds
+    assert [s["counts"] for s in recorded
+            if s["name"] == "period.history"] == [{spans.HOST_PULLS: 8}] * 3
 
 
 def test_log_time_is_the_dispatch_and_wait_spans(fed4):
@@ -188,6 +195,15 @@ def test_run_service_checkpoints_every_checkpoint_every(fed4, tmp_path):
                      "period.history"]
     collect = [s for s in recorded if s["name"] == "ledger.collect"]
     assert all(s["counts"] == {spans.HOST_PULLS: 3} for s in collect)
+    # one pull per history array (the service's 10 round metrics, each
+    # of length 2), per transported array and per checkpointed leaf
+    history = [s for s in recorded if s["name"] == "period.history"]
+    assert [s["counts"] for s in history] == [{spans.HOST_PULLS: 10}] * 5
+    leaves = len(jax.tree.leaves(state))
+    per_period = [sum(s["counts"].get(spans.HOST_PULLS, 0)
+                      for s in _under(recorded, p))
+                  for p in recorded if p["name"] == "period"]
+    assert per_period == [10 + 3, 10 + 3 + leaves] * 2 + [10 + 3]
 
 
 # ------------------------------------------------------------ shared clock
