@@ -20,7 +20,6 @@ import sys
 
 import run  # noqa: F401  (puts the harness and the program on the path)
 import compare
-import traffic
 
 PLANTED = {"control": {"dtype": "bfloat16"},
            "half_batch": {"fault": "half_batch"}}
@@ -29,7 +28,7 @@ PLANTED = {"control": {"dtype": "bfloat16"},
 def readings(cell, seed, kinds=tuple(PLANTED)):
     import jax.numpy as jnp
     wl = cell["wl"]
-    data = traffic.generate(cell["cfg"], wl, seed)
+    data = run.cell_data(cell, seed)
     ref = run.reference_readings(cell, seed, data, wl["check_periods"])
     out = {}
     for kind in kinds:

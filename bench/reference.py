@@ -13,13 +13,17 @@ the period's selection. Clients run one at a time under lax.map, so
 the reference fits beside the inputs at the cells' sizes.
 
 `dtype` float32 runs under matmul precision "highest"; bfloat16 casts
-weights, inputs and optimizer moments down and is the control that
-the comparison has to refuse. `fault` plants one of the faults the
-comparison has to catch ("half_batch": the local loss averages over
-the first half of each minibatch).
+weights, the shared weights, floating inputs and optimizer moments
+down and is the control that the comparison has to refuse. Integer
+inputs (labels, token ids) are never cast. Where the data hold
+"shared", the configuration's frozen weights that all clients share,
+the model is applied as `apply(p, x, shared)`. `fault` plants one of
+the faults the comparison has to catch ("half_batch": the local loss
+averages over the first half of each minibatch).
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -38,6 +42,14 @@ class State(NamedTuple):
     commitments: jnp.ndarray
     rng: jnp.ndarray
     round: jnp.ndarray
+
+
+def cast_floating(tree, dtype):
+    """The tree with its floating leaves in `dtype`; integer leaves,
+    and leaves already in `dtype`, as they are."""
+    return jax.tree.map(
+        lambda v: v.astype(dtype) if (jnp.issubdtype(v.dtype, jnp.floating)
+                                      and v.dtype != dtype) else v, tree)
 
 
 # --------------------------------------------------------------- hashing
@@ -248,8 +260,15 @@ class Federation:
                 return jitted(*args)
         return run
 
-    def apply(self, p, x):
-        return self.model.apply(p, x.astype(self.dtype))
+    def apply(self, p, x, shared=None):
+        x = cast_floating(x, self.dtype)
+        if shared is None:
+            return self.model.apply(p, x)
+        return self.model.apply(p, x, shared)
+
+    def _applier(self, data):
+        """`apply(p, x)` with the data's shared weights, if any."""
+        return functools.partial(self.apply, shared=data.get("shared"))
 
     def init(self, key):
         keys = jax.random.split(key, self.m)
@@ -267,14 +286,15 @@ class Federation:
                      jax.random.fold_in(key, 1), jnp.zeros((), jnp.int32))
 
     def _exchange_update(self, st, data, ids, sel_mask, rng_upd):
+        apply = self._applier(data)
         l_ij, valid, target, has = exchange(
-            self.apply, st.params, data["x_ref"], data["y_ref"], ids,
+            apply, st.params, data["x_ref"], data["y_ref"], ids,
             sel_mask, self.public)
         x_ref = data["x_ref"]
         if self.public:              # every client distills on row 0
             x_ref = jnp.broadcast_to(x_ref[0], x_ref.shape)
         params, opt, loss = update(
-            self.apply, self.fed, st.params, st.opt, data, x_ref, target,
+            apply, self.fed, st.params, st.opt, data, x_ref, target,
             has, rng_upd, self.fault)
         return l_ij, params, opt, loss
 
@@ -293,7 +313,7 @@ class Federation:
         new = State(params, opt, codes, rankings, fnv1a(rankings), rng,
                     st.round + 1)
         out = {"loss": jnp.mean(loss.astype(jnp.float32)),
-               "acc": accuracy(self.apply, params, data)}
+               "acc": accuracy(self._applier(data), params, data)}
         return new, (ids, sel_mask), out
 
     def _gossip(self, st, data, sel):
@@ -303,15 +323,14 @@ class Federation:
         new = st._replace(params=params, opt=opt, rng=rng,
                           round=st.round + 1)
         out = {"loss": jnp.mean(loss.astype(jnp.float32)),
-               "acc": accuracy(self.apply, params, data)}
+               "acc": accuracy(self._applier(data), params, data)}
         return new, sel, out
 
     def run(self, key, data, periods, length):
         """`periods` reselection periods of `length` rounds from the
         seed's initial state. Returns the initial state, the state after
         each period and each round's loss and accuracy."""
-        data = {k: (v.astype(self.dtype) if v.dtype == jnp.float32 else v)
-                for k, v in data.items()}
+        data = cast_floating(data, self.dtype)
         st = self.init(key)
         states, rounds = [st], []
         for _ in range(periods):

@@ -24,12 +24,35 @@ under a traffic mix (bench/workloads/<traffic>.json). The run
 
 It exits non-zero and prints no result where JAX finds no TPU or
 fewer chips than the cell asks for.
+
+A configuration is two files, found by its name alone:
+
+  <config>.json  "model": the client model block. Every key of it
+                 reaches the program (`client_model_config`): those
+                 the program's ClientModelConfig names as fields, and
+                 all others in its `arch`, as sorted (key, value)
+                 pairs. "input": "tokens" with "vocab": V makes the
+                 traffic int32 ids in [0, V) of shape input_shape in
+                 place of float rows (bench/traffic.py). "fed": the
+                 FedConfig fields the configuration sets.
+  <config>.py    the plain reference: `init(cfg, key)` one client's
+                 trainable tree, the tree the program trains too;
+                 `apply(p, x)` logits; `forward_flops(cfg)` one
+                 example's forward FLOPs. Optionally
+                 `init_shared(cfg, key)`: frozen weights that all
+                 clients share, made once a run during set-up, given
+                 to the program as data["shared"] and to the
+                 reference as `apply(p, x, shared)`; and
+                 `train_flops(cfg)`: one example's forward and
+                 backward FLOPs of what is trained, which the work
+                 count takes in place of 3 x forward_flops.
 """
 import time
 
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
 import functools  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -51,6 +74,10 @@ import work  # noqa: E402
 
 # A traced run profiles at least this many steady periods and seconds.
 TRACE_PERIODS, TRACE_SECONDS = 3, 1.0
+
+# folded into the seed's key for the shared weights; no other draw
+# folds it in (the program and the reference fold in 1)
+SHARED_FOLD = 0x53484152
 
 COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
                   "/jax/core/compile/backend_compile_duration")
@@ -225,29 +252,77 @@ def _logged(lines):
 
 
 # ----------------------------------------------------------- the program
+def _frozen(value):
+    """A JSON value as a hashable one: lists as tuples, objects as
+    sorted (key, value) pairs."""
+    if isinstance(value, dict):
+        return tuple(sorted((k, _frozen(v)) for k, v in value.items()))
+    if isinstance(value, list):
+        return tuple(_frozen(v) for v in value)
+    return value
+
+
+@functools.lru_cache(maxsize=None)
+def _config_class():
+    """The program's ClientModelConfig with an `arch` field, empty by
+    default, that holds the model block's other keys: the program's
+    own class once it has that field, until then a subclass that adds
+    it and nothing else."""
+    from repro.configs.paper_models import ClientModelConfig
+    if "arch" in {f.name for f in dataclasses.fields(ClientModelConfig)}:
+        return ClientModelConfig
+    return dataclasses.make_dataclass(
+        "ClientModelConfig",
+        [("arch", tuple, dataclasses.field(default=()))],
+        bases=(ClientModelConfig,), frozen=True)
+
+
+def client_model_config(cfg):
+    """The program's client model configuration from a configuration's
+    `model` block: each key that names a field, the rest in `arch`."""
+    cls = _config_class()
+    fields = {f.name for f in dataclasses.fields(cls)} - {"name", "arch"}
+    block = {k: _frozen(v) for k, v in cfg["model"].items()}
+    return cls(name=cfg["name"],
+               arch=tuple(sorted((k, v) for k, v in block.items()
+                                 if k not in fields)),
+               **{k: v for k, v in block.items() if k in fields})
+
+
+def cell_data(cell, seed):
+    """The cell's inputs from the seed: the traffic's stacked arrays
+    and, where the configuration's module defines `init_shared`, under
+    "shared" the frozen weights all clients share, made once on the
+    device in one jitted call."""
+    import jax
+    data = traffic.generate(cell["cfg"], cell["wl"], seed)
+    init_shared = getattr(cell["model"], "init_shared", None)
+    if init_shared is not None:
+        key = jax.random.fold_in(traffic.seed_key(seed), SHARED_FOLD)
+        data["shared"] = jax.jit(
+            functools.partial(init_shared, cell["cfg"]))(key)
+    return data
+
+
 def program_parts(cell, seed):
     """The system under test, configured from the cell's files, with
     its data and initial state made from the seed."""
     import jax
-    from repro.configs.paper_models import (ClientModelConfig, FedConfig,
-                                            recommended_dedupe)
+    from repro.configs.paper_models import FedConfig, recommended_dedupe
     from repro.core import init_state
     from repro.models import apply_client_model
     from repro.optim import adam
 
     cfg, wl = cell["cfg"], cell["wl"]
-    mod = cfg["model"]
-    mcfg = ClientModelConfig(cfg["name"], mod["kind"],
-                             tuple(mod["input_shape"]), mod["num_classes"],
-                             tuple(mod["hidden"]), mod["kernel_size"])
-    apply_fn = functools.partial(apply_client_model, mcfg)
+    apply_fn = functools.partial(apply_client_model,
+                                 client_model_config(cfg))
     init_fn = functools.partial(cell["model"].init, cfg)
     m, ref_mode = wl["clients"], wl["ref_mode"]
     fed = FedConfig(num_clients=m, ref_mode=ref_mode,
                     dedupe_rankings=recommended_dedupe(ref_mode),
                     **cfg["fed"])
     opt = adam(fed.lr)
-    data = traffic.generate(cfg, wl, seed)
+    data = cell_data(cell, seed)
     state = jax.jit(functools.partial(init_state, apply_fn, init_fn, opt,
                                       fed))(traffic.seed_key(seed))
     jax.block_until_ready((data, state))
@@ -424,19 +499,29 @@ def per_layer(cell, ctx):
     return out
 
 
+def period_flops(cell, n):
+    """Client-model FLOPs of one period with `n` neighbors: the
+    configuration's counts (`forward_flops`, and `train_flops` where
+    its module defines it) through `work.round_flops`."""
+    wl, cfg, model = cell["wl"], cell["cfg"], cell["model"]
+    train = getattr(model, "train_flops", None)
+    return wl["reselect_every"] * work.round_flops(
+        model.forward_flops(cfg), wl["clients"], n, cfg["fed"], wl,
+        wl["ref_mode"] == "public",
+        train_flops=None if train is None else train(cfg))
+
+
 def trace_context(cell, events, clock, device, pk, fed):
     """What the per-layer readers see of a traced run."""
     import numpy as np
     wl, cfg = cell["wl"], cell["cfg"]
-    m, g = wl["clients"], wl["reselect_every"]
+    m = wl["clients"]
     n = min(fed.num_neighbors, m - 1)
-    public = wl["ref_mode"] == "public"
     c = cfg["model"]["num_classes"]
     return {
         "events": events, "window": tr.window(events),
         "periods_s": list(np.diff(clock.window())),
-        "period_flops": g * work.round_flops(
-            cell["model"].forward_flops(cfg), m, n, cfg["fed"], wl, public),
+        "period_flops": period_flops(cell, n),
         "lsh": work.lsh_work(m, cfg["params"], cfg["fed"]["lsh_bits"]),
         "exchange": work.exchange_work(m, n, wl["ref_rows"], c),
         "peaks": pk, "memory_peak_bytes": device["memory_peak_bytes"],

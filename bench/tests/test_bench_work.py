@@ -53,6 +53,10 @@ def test_round_flops_by_hand():
     assert work.round_flops(7, 1024, 10, fed, wl, True) == 7 * per_fwd
     personal = 1024 * 5 * 112 * 3 + 1024 * 10 * 48 + 1024 * 48 + 1024 * 87
     assert work.round_flops(7, 1024, 10, fed, wl, False) == 7 * personal
+    # a configuration that counts its own training cost (a frozen base)
+    trained = 1024 * 5 * 112 * 11 + 7 * (1024 * 48 + 1024 * 87)
+    assert work.round_flops(7, 1024, 10, fed, wl, True,
+                            train_flops=11) == trained
 
 
 def test_roofline_bound_is_the_larger_time():

@@ -12,6 +12,15 @@ matters. Reference rows cover all classes with the client's own
 labels. Under ref_mode "public" the program reads row 0 of x_ref and
 y_ref as the shared set. The same seed gives the same arrays; another
 seed gives other values of the same sizes.
+
+Where the model block says "input": "tokens" with "vocab": V, a
+class's template is a vector of random logits over the V ids instead,
+and each id of a row is drawn on its own from the softmax of its
+class's logits at temperature `noise`. That is plumbing for ids, not
+a deployment's text: no order within a row, no Zipf-like frequency of
+ids. A cell whose layers depend on token statistics, such as expert
+routing, cites the source of its id distribution, or states that
+those layers are not measured.
 """
 import functools
 
@@ -25,13 +34,30 @@ def seed_key(seed: int):
     return jax.random.fold_in(key, (seed >> 31) & 0xFFFFFFFF)
 
 
-@functools.partial(jax.jit, static_argnames=("shape", "classes", "wl"))
-def _generate(key, *, shape, classes, wl):
+def _tokens(key, cdf, y, shape):
+    """Ids of shape y.shape + shape, each drawn by its row's class from
+    `cdf` (classes, V), that class's cumulative distribution: the least
+    id whose cumulative share exceeds a uniform draw."""
+    u = jax.random.uniform(key, y.shape + shape)
+    ids = jax.vmap(lambda c: jnp.searchsorted(c, u, side="right"))(cdf)
+    cls = y.reshape((1,) + y.shape + (1,) * len(shape))
+    ids = jnp.take_along_axis(ids, cls, axis=0)[0]
+    # a draw at or above a total that rounded under 1 takes the last id
+    return jnp.minimum(ids, cdf.shape[1] - 1).astype(jnp.int32)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("shape", "classes", "vocab", "wl"))
+def _generate(key, *, shape, classes, vocab, wl):
     wl = dict(wl)
     m, k_cls = wl["clients"], wl["classes_per_client"]
     clusters, noise = wl["label_clusters"], wl["noise"]
     kt, kc, kd = jax.random.split(key, 3)
-    templates = jax.random.normal(kt, (classes,) + shape)
+    if vocab is None:
+        templates = jax.random.normal(kt, (classes,) + shape)
+    else:
+        logits = jax.random.normal(kt, (classes, vocab))
+        cdf = jnp.cumsum(jax.nn.softmax(logits / noise, axis=-1), axis=-1)
     # each client's classes: the first k of a random permutation
     perm = jax.vmap(lambda k: jax.random.permutation(k, classes))(
         jax.random.split(kc, m))
@@ -42,8 +68,11 @@ def _generate(key, *, shape, classes, wl):
         ky, kx = jax.random.split(key)
         pick = jax.random.randint(ky, (m, n), 0, pool.shape[1])
         y = jnp.take_along_axis(pool, pick, axis=1)          # (M, n)
-        x = templates[y] + noise * jax.random.normal(
-            kx, (m, n) + shape)
+        if vocab is None:
+            x = templates[y] + noise * jax.random.normal(
+                kx, (m, n) + shape)
+        else:
+            x = _tokens(kx, cdf, y, shape)
         return x, ((y + shift[:, None]) % classes).astype(jnp.int32)
 
     k1, k2, k3 = jax.random.split(kd, 3)
@@ -60,8 +89,14 @@ GEN_KEYS = ("clients", "classes_per_client", "label_clusters", "noise",
 
 
 def generate(cfg: dict, wl: dict, seed: int) -> dict:
-    """Stacked (M, rows, ...) float32 inputs and int32 labels."""
+    """Stacked (M, rows, ...) inputs, float32 rows or int32 ids, and
+    int32 labels."""
     model = cfg["model"]
+    kind = model.get("input", "features")
+    if kind not in ("features", "tokens"):
+        raise ValueError(f"unknown model input {kind!r}: "
+                         "'features' or 'tokens'")
     return _generate(seed_key(seed), shape=tuple(model["input_shape"]),
                      classes=model["num_classes"],
+                     vocab=model["vocab"] if kind == "tokens" else None,
                      wl=tuple((k, wl[k]) for k in GEN_KEYS))

@@ -28,17 +28,20 @@ def peaks(device_kind: str) -> dict:
 
 
 def round_flops(forward_flops: int, m: int, n: int, fed: dict, wl: dict,
-                public: bool) -> int:
+                public: bool, train_flops: int | None = None) -> int:
     """Client-model FLOPs of one round: the local update's forward and
-    backward (3 forward costs) over each step's batch and the reference
-    rows, the exchange's forwards (M*N*R personal, M*R public, plus
-    each client's own M*R when personal) and the evaluation."""
+    backward over each step's batch and the reference rows
+    (`train_flops` an example where given, as for a frozen base that
+    takes no weight gradients, else 3 forward costs), the exchange's
+    forwards (M*N*R personal, M*R public, plus each client's own M*R
+    when personal) and the evaluation."""
     r = wl["ref_rows"]
     batch = min(fed["local_batch"], wl["train_rows"])
-    update = m * fed["local_steps"] * (batch + r) * 3
+    train = 3 * forward_flops if train_flops is None else train_flops
+    update = m * fed["local_steps"] * (batch + r) * train
     exchange = m * r if public else m * n * r + m * r
     evaluate = m * wl["test_rows"]
-    return forward_flops * (update + exchange + evaluate)
+    return update + forward_flops * (exchange + evaluate)
 
 
 def lsh_work(m: int, p: int, bits: int) -> tuple:
