@@ -45,7 +45,20 @@ A configuration is two files, found by its name alone:
                  reference as `apply(p, x, shared)`; and
                  `train_flops(cfg)`: one example's forward and
                  backward FLOPs of what is trained, which the work
-                 count takes in place of 3 x forward_flops.
+                 count takes in place of 3 x forward_flops; and
+                 `layer_work(cfg, wl)`: {name: (FLOPs, bytes)} of one
+                 period of the configuration's own layers, which a
+                 traced run hands its per-layer metrics as
+                 ctx["layer_work"] ({} where the module has none).
+
+A configuration cut from a published model lists each key it changed
+in its BENCHMARK.json entry's "reduced" (names, never a width), and
+its JSON then holds a "deployment" string: over how many chips each
+layer is divided, and what this chip holds of it. A cell's "chips" is
+1 or 4; at most half the cells, rounded down, and always one, may ask
+for 4. A "cpu" block in the configuration's JSON (overrides of
+"model", "fed" and "params") is the size the harness's own CPU tests
+run it at (bench/tests/benchkit.py); no run of this command reads it.
 """
 import time
 
@@ -121,7 +134,7 @@ def load_cell(name: str, root: str = ROOT) -> dict:
         return name in metric.get("workloads", [name])
 
     return {
-        "name": name, "entry": entry, "cfg": cfg,
+        "name": name, "entry": entry, "cfg": cfg, "bench": bench_dir,
         "model": _module(ref_path, "model_" + re.sub(r"\W", "_",
                                                       conf["name"])),
         "wl": _json(os.path.join(bench_dir, "workloads",
@@ -491,7 +504,8 @@ def period_stats(clock, rounds_per_period, clients):
 def per_layer(cell, ctx):
     out = {}
     for metric in cell["per_layer"]:
-        path = os.path.join(BENCH, "metrics", metric["name"] + ".py")
+        path = os.path.join(cell.get("bench", BENCH), "metrics",
+                            metric["name"] + ".py")
         value = _module(path, "metric_" + re.sub(r"\W", "_",
                                                   metric["name"])).read(ctx)
         if value is not None:
@@ -515,6 +529,7 @@ def trace_context(cell, events, clock, device, pk, fed):
     """What the per-layer readers see of a traced run."""
     import numpy as np
     wl, cfg = cell["wl"], cell["cfg"]
+    layer_work = getattr(cell["model"], "layer_work", None)
     m = wl["clients"]
     n = min(fed.num_neighbors, m - 1)
     c = cfg["model"]["num_classes"]
@@ -524,6 +539,7 @@ def trace_context(cell, events, clock, device, pk, fed):
         "period_flops": period_flops(cell, n),
         "lsh": work.lsh_work(m, cfg["params"], cfg["fed"]["lsh_bits"]),
         "exchange": work.exchange_work(m, n, wl["ref_rows"], c),
+        "layer_work": {} if layer_work is None else layer_work(cfg, wl),
         "peaks": pk, "memory_peak_bytes": device["memory_peak_bytes"],
     }
 

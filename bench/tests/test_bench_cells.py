@@ -2,68 +2,30 @@
 finding a cell's files by name alone."""
 import json
 import os
-import re
 import shutil
 
 import pytest
 
-from benchkit import BENCH, CELLS
+import benchkit
+from benchkit import BENCH, ROOT
 
 import run
 
-ROOT = os.path.dirname(BENCH)
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+
+def test_top_level_keys():
+    benchkit.check_top_level(ROOT)
 
 
-@pytest.fixture(scope="module")
-def bench():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
+def test_names_units_and_files():
+    benchkit.check_names_units_and_files(ROOT)
 
 
-def test_top_level_keys(bench):
-    assert set(bench) == {"command", "paths", "run_seconds", "configs",
-                          "workloads", "end_to_end", "per_layer"}
-    assert bench["paths"] == ["bench"]
-    assert 1 <= bench["run_seconds"] <= 51
-    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 65536
+def test_end_to_end_metrics_and_bounds():
+    benchkit.check_end_to_end(ROOT)
 
 
-def test_names_units_and_files(bench):
-    metrics = bench["end_to_end"] + bench["per_layer"]
-    names = [x["name"] for x in bench["configs"] + bench["workloads"]
-             + metrics]
-    assert all(NAME.match(n) for n in names)
-    assert len({m["name"] for m in metrics}) == len(metrics)
-    assert all(UNIT.match(m["unit"]) for m in metrics)
-    assert all(m["better"] in ("lower", "higher") for m in metrics)
-    for c in bench["configs"]:
-        assert c["file"].startswith("bench/") and c["reduced"] == []
-        assert os.path.exists(os.path.join(ROOT, c["file"]))
-    for w in bench["workloads"]:
-        assert w["chips"] == 1 and len(w["why"]) <= 200
-        assert os.path.exists(os.path.join(
-            BENCH, "workloads", w["traffic"] + ".json"))
-        assert os.path.exists(os.path.join(BENCH, "limits",
-                                           w["name"] + ".json"))
-    for m in bench["per_layer"]:
-        assert os.path.exists(os.path.join(BENCH, "metrics",
-                                           m["name"] + ".py"))
-        assert m["moves"] in {e["name"] for e in bench["end_to_end"]}
-
-
-def test_end_to_end_metrics_and_bounds(bench):
-    e2e = {m["name"]: m for m in bench["end_to_end"]}
-    assert set(e2e) == {"client_rounds_per_s", "period_p90_ms", "setup_s"}
-    assert all(0.01 <= m["bound"] <= 0.25 for m in e2e.values())
-    assert all(m["source"] == "host_clock" for m in e2e.values())
-
-
-def test_every_config_has_a_cell(bench):
-    used = {w["config"] for w in bench["workloads"]}
-    assert used == {c["name"] for c in bench["configs"]}
-    assert len(CELLS) >= 1
+def test_every_config_has_a_cell():
+    benchkit.check_every_config_has_a_cell(ROOT)
 
 
 def test_an_added_workload_is_found_by_name(tmp_path):
@@ -92,3 +54,45 @@ def test_an_added_workload_is_found_by_name(tmp_path):
         "client_rounds_per_s", "period_p90_ms", "setup_s"}
     with pytest.raises(KeyError):
         run.load_cell("no-such-cell", root=str(tmp_path))
+
+
+def _cut(bench, cfg):
+    bench["configs"][0]["reduced"] = ["num_hidden_layers"]
+    cfg.pop("deployment", None)
+
+
+def _width(bench, cfg):
+    bench["configs"][0]["reduced"] = ["intermediate_size"]
+    cfg["deployment"] = "2 chips share each layer"
+
+
+def _two_chips(bench, cfg):
+    bench["workloads"][0]["chips"] = 2
+
+
+def _four_chip_majority(bench, cfg):
+    for w in bench["workloads"][:len(bench["workloads"]) // 2 + 1]:
+        w["chips"] = 4
+
+
+@pytest.mark.parametrize("breach", [_cut, _width, _two_chips,
+                                    _four_chip_majority],
+                         ids=lambda f: f.__name__.strip("_"))
+def test_the_contract_checks_refuse_a_breach(tmp_path, breach):
+    """A cut with no deployment, a width in "reduced", a cell on 2
+    chips, and 4 chips in more than half the cells are refused."""
+    shutil.copytree(BENCH, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), tmp_path)
+    with open(tmp_path / "BENCHMARK.json") as f:
+        bench = json.load(f)
+    cfg_path = tmp_path / bench["configs"][0]["file"]
+    with open(cfg_path) as f:
+        cfg = json.load(f)
+    benchkit.check_names_units_and_files(str(tmp_path))
+    breach(bench, cfg)
+    for path, obj in ((tmp_path / "BENCHMARK.json", bench), (cfg_path, cfg)):
+        with open(path, "w") as f:
+            json.dump(obj, f)
+    with pytest.raises(AssertionError):
+        benchkit.check_names_units_and_files(str(tmp_path))
