@@ -25,11 +25,13 @@ across the mesh for TPU-scale runs.
 """
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro import spans
 from repro.analysis.privacy import sink
 from repro.configs.paper_models import FedConfig
 from repro.core import distill, lsh, neighbor, ranking, verify
@@ -251,22 +253,66 @@ def _local_update(apply_fn, optimizer, fed: FedConfig, params, opt_state,
                                "ref_loss": l_refs[-1]}
 
 
-def batched_local_update(apply_fn, optimizer, fed: FedConfig, params,
-                         opt_state, data_per, target_ref, has_target, keys):
-    """Per-client local updates over the stacked (M, ...) axis.
+# the lanes a chunk of clients fills in a conv's channel axis, twice
+# the vector unit's 128: on a v5e the TCN's width-32 update ran
+# fastest at 8 clients a step (PERF.md §6: 4 and 16 were slower)
+CHUNK_LANES = 256
+# counted each time the update traces: the chunk width it chose
+UPDATE_CHUNK = "update.chunk"
 
-    Uses ``lax.map`` rather than ``vmap``: vmapping convolutions over
-    per-client *weights* forces XLA-CPU onto a grouped-conv path whose
-    gradients are ~25x slower (measured); sequential per-client bodies
-    keep the fast path. On TPU the client axis is sharded by
-    launch/fed.py, so the inner loop stays short there too.
-    """
+
+def update_chunk(backend: str, m: int, shapes) -> int:
+    """How many clients one step of the local update runs at once.
+
+    `shapes` are one client's parameter leaf shapes. Under ``vmap`` a
+    convolution over per-client weights folds the clients into its
+    channel axis (``feature_group_count``), so k clients of a conv of
+    width w fill k*w lanes where one client pads w to 128. The width
+    is the narrowest output channel count of the conv kernels (leaves
+    of rank >= 3: window..., in, out), and k the fewest clients that
+    fill `CHUNK_LANES`, capped at M.
+
+    1 where the conv kernels hold less than half of the parameters:
+    dense layers batch as separate matmuls and gain nothing, and on a
+    v5e chunking a client whose parameters sit in a 3136x128 dense
+    layer slowed the period program by 11% (PERF.md §6). 1 on the
+    CPU too: XLA-CPU runs grouped-conv gradients ~25x slower than
+    plain ones (measured)."""
+    conv = [s for s in shapes if len(s) >= 3]
+    if (backend == "cpu" or 2 * sum(map(math.prod, conv))
+            < sum(map(math.prod, shapes))):
+        return 1
+    return min(m, -(-CHUNK_LANES // min(s[-1] for s in conv)))
+
+
+def _chunked_local_update(apply_fn, optimizer, fed: FedConfig, params,
+                          opt_state, data_per, target_ref, has_target,
+                          keys, width: int):
+    """`_local_update` over the stacked (M, ...) axis, `width` clients
+    a step (a remainder chunk where `width` does not divide M); 1 is
+    the plain one-client ``lax.map``."""
     def one(args):
         p, s, d, t, h, k = args
         return _local_update(apply_fn, optimizer, fed, p, s, d, t, h, k)
 
     return jax.lax.map(one, (params, opt_state, data_per, target_ref,
-                             has_target, keys))
+                             has_target, keys),
+                       batch_size=width if width > 1 else None)
+
+
+def batched_local_update(apply_fn, optimizer, fed: FedConfig, params,
+                         opt_state, data_per, target_ref, has_target, keys):
+    """Per-client local updates over the stacked (M, ...) axis, a
+    chunk of clients at a time under ``lax.map``: `update_chunk` picks
+    the width from the backend, M and one client's parameter shapes,
+    and the trace counts it as `UPDATE_CHUNK`."""
+    leaves = jax.tree.leaves(params)
+    width = update_chunk(jax.default_backend(), leaves[0].shape[0],
+                         [x.shape[1:] for x in leaves])
+    spans.count(UPDATE_CHUNK, width)
+    return _chunked_local_update(apply_fn, optimizer, fed, params,
+                                 opt_state, data_per, target_ref,
+                                 has_target, keys, width)
 
 
 # ---------------------------------------------------------------------------
@@ -358,10 +404,10 @@ def make_wpfed_round(apply_fn: Callable, optimizer: Optimizer,
 def evaluate(apply_fn, state: FedState, data, honest_mask=None):
     """Per-client test accuracy; mean over honest clients if mask given.
 
-    One client per step under ``lax.map``, like the local update: on a
-    TPU v5e the vmapped form returned wrong accuracies for 6 of 10
-    mnist-cnn clients although its logits equalled the per-client
-    forward's (PERF.md §6)."""
+    One client per step under ``lax.map``, unlike the local update's
+    chunks: on a TPU v5e the vmapped form returned wrong accuracies for
+    6 of 10 mnist-cnn clients although its logits equalled the
+    per-client forward's (PERF.md §6)."""
     acc = jax.lax.map(
         lambda a: distill.accuracy(apply_fn(a[0], a[1]), a[2]),
         (state.params, data["x_test"], data["y_test"]))

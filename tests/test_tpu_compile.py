@@ -8,6 +8,11 @@ Shapes are the federation's own: mnist-cnn's parameter count for LSH,
 selection on each side of the one-shot/tiled and exact/ANN switches,
 and the paper's exchange shape (M=16 after padding, N=9, R=64, C=10).
 
+The local update's chunked form (`protocol._chunked_local_update` at
+the width `update_chunk` gives a TPU) compiles too, at the benchmark's
+m1024 TCN shapes and at M=10 (a remainder chunk), within a few GB of
+scratch memory.
+
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU compiler's library.
 """
@@ -154,3 +159,44 @@ def test_pallas_name_sets_the_instruction_name(sds):
     text = wrapper.lower(sds((8, 128), jnp.float32)).compile().as_text()
     names = CUSTOM_CALL.findall(text)
     assert len(names) == 1 and names[0].startswith("kernel_name"), names
+
+
+# (model, M, train rows, reference rows) of the benchmark's TCN
+# configuration; 5 local steps of 64 rows
+UPDATE_CASES = {"aecg_tcn-m1024": ("aecg_tcn", 1024, 201, 48),
+                "aecg_tcn-m10": ("aecg_tcn", 10, 201, 48)}
+
+
+@pytest.mark.parametrize("case", list(UPDATE_CASES))
+def test_chunked_update_compiles_for_v5e(case, sds):
+    import functools
+    from repro.configs import paper_models
+    from repro.core import protocol
+    from repro.models import apply_client_model, init_client_model
+    from repro.optim import adam
+    kind, m, rows, ref_rows = UPDATE_CASES[case]
+    mcfg = getattr(paper_models, kind)()
+    fed = paper_models.FedConfig(num_clients=m, local_steps=5,
+                                 local_batch=64)
+    opt = adam(fed.lr)
+    one = jax.eval_shape(functools.partial(init_client_model, mcfg),
+                         jax.random.PRNGKey(0))
+    width = protocol.update_chunk(
+        "tpu", m, [x.shape for x in jax.tree.leaves(one)])
+    assert width > 1, case
+    stacked = lambda t: jax.tree.map(
+        lambda x: sds((m,) + x.shape, x.dtype), t)
+    x = mcfg.input_shape
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.PRNGKey(0), m))
+    compiled = jax.jit(functools.partial(
+        protocol._chunked_local_update,
+        functools.partial(apply_client_model, mcfg), opt, fed,
+        width=width)).lower(
+        stacked(one), stacked(jax.eval_shape(opt.init, one)),
+        {"x_train": sds((m, rows) + x, jnp.float32),
+         "y_train": sds((m, rows), jnp.int32),
+         "x_ref": sds((m, ref_rows) + x, jnp.float32),
+         "y_ref": sds((m, ref_rows), jnp.int32)},
+        sds((m, ref_rows, mcfg.num_classes), jnp.float32),
+        sds((m,), jnp.bool_), sds(keys.shape, keys.dtype)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9, case
