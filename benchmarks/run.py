@@ -1,9 +1,9 @@
-"""Benchmark entrypoint: one function per paper table/figure. Prints
-``name,us_per_call,derived`` CSV rows for micro-benches and summary lines
-for the experiment tables.
+"""Paper-reproduction entrypoint: one function per paper table/figure,
+each printing summary lines of accuracy, not speed (the chip benchmark
+is `bench/run.py`).
 
     PYTHONPATH=src python -m benchmarks.run             # full suite
-    PYTHONPATH=src python -m benchmarks.run --only table2,kernels --fast
+    PYTHONPATH=src python -m benchmarks.run --only table2,fig4 --fast
 """
 from __future__ import annotations
 
@@ -19,8 +19,7 @@ RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "results")
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="",
-                    help="comma list: kernels,roofline,table2,table3,"
-                         "fig3,fig4,fig5")
+                    help="comma list: table2,table3,fig3,fig4,fig5")
     ap.add_argument("--fast", action="store_true",
                     help="fewer rounds/seeds (CI budget)")
     args = ap.parse_args()
@@ -34,26 +33,6 @@ def main() -> None:
         return only is None or name in only
 
     t0 = time.time()
-
-    if want("kernels"):
-        print("# kernel micro-benchmarks "
-              "(name,us_per_call,tpu_est_us,spread_pct)")
-        from benchmarks import kernel_micro
-        # explicit argv: kernel_micro must not re-parse run.py's flags,
-        # and its selection baseline goes to RESULTS_DIR — only a direct
-        # kernel_micro invocation rewrites the committed baseline.
-        rounds_out = ["--rounds-json-out",
-                      os.path.join(RESULTS_DIR, "BENCH_rounds.json")]
-        outputs["kernels"] = kernel_micro.main(
-            (["--smoke"] if args.fast else
-             ["--json-out", os.path.join(RESULTS_DIR,
-                                         "BENCH_selection.json")])
-            + rounds_out)
-
-    if want("roofline"):
-        print("\n# roofline (from dry-run sweeps)")
-        from benchmarks import roofline
-        roofline.main()
 
     seeds = (0,) if args.fast else (0, 1)
     rounds = 5 if args.fast else 8

@@ -1,16 +1,12 @@
 #!/usr/bin/env bash
-# One-command verify recipe: tier-1 tests + kernel micro-benchmark
-# (smoke mode — covers LSH projection, Hamming, fused selection, the
-# fused all-in-one exchange, the round-program engine and the adversary
-# instrumentation, emitting benchmarks/BENCH_rounds.json +
-# BENCH_adversary.json) + the VMEM-tiled kernel smoke (DESIGN.md §10:
-# tiled selection/exchange in interpret mode at shapes whose one-shot
-# working set exceeds the VMEM budget) + a reduced-scale run of the
-# attack-resilience example (the in-graph ThreatModel path end-to-end,
-# attacks firing inside a gossip segment) + the §11 ANN selection
-# smoke (sub-quadratic candidate path at M=16384 — beyond the exact
-# kernels' comfortable range — plus recall and the one-bucket
-# bit-exact fallback) + the §13 continuous-service smoke (3 churned
+# One-command verify recipe: tier-1 tests + the VMEM-tiled kernel
+# smoke (DESIGN.md §10: tiled selection/exchange in interpret mode at
+# shapes whose one-shot working set exceeds the VMEM budget) + a
+# reduced-scale run of the attack-resilience example (the in-graph
+# ThreatModel path end-to-end, attacks firing inside a gossip
+# segment) + the §11 ANN selection smoke (sub-quadratic candidate
+# path at M=16384 — beyond the exact kernels' comfortable range —
+# plus recall and the one-bucket bit-exact fallback) + the §13 continuous-service smoke (3 churned
 # reselection periods, kill after 2, bit-exact resume + ledger
 # verification across the restart, batched personalized serving)
 # + the §15 chaos soak (every fault kind of a seeded FaultPlan firing
@@ -52,9 +48,6 @@ done
 
 echo "== tier-1: pytest =="
 python -m pytest -x -q "$@"
-
-echo "== kernel micro-benchmark (smoke) =="
-python benchmarks/kernel_micro.py --smoke
 
 echo "== tiled kernels beyond the one-shot VMEM budget (smoke) =="
 python scripts/tiled_smoke.py

@@ -89,8 +89,8 @@ def bucket_cap(m: int, prefix_bits: int, num_neighbors: int) -> int:
     a whole top-N by itself), never more than M. The 4x multiplier is
     measured, not guessed: clustered codes concentrate whole clusters
     into single buckets (occupancy ~ M/n_clusters, not M/B), and at 2x
-    the overflow drops cost ~10 recall points on the benchmark sweep
-    (BENCH_selection.json records `dropped_candidates` so the effect
+    the overflow drops cost ~10 recall points on a clustered-code
+    sweep (`occupancy_stats` reports `dropped_candidates` so the effect
     stays observable). prefix_bits=0 forces cap=M — the one-bucket
     exact fallback."""
     n_buckets = 1 << effective_prefix_bits(prefix_bits, 1 << 30)

@@ -23,7 +23,7 @@ import json
 import os
 import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax.numpy as jnp
 import numpy as np
@@ -184,6 +184,22 @@ def verify_reveal(commitment_hex: str, revealed_ranking, salt: int = 0) -> bool:
 def lsh_code_hex(code) -> str:
     # analysis: host-ok — announcement serialization for the host ledger
     return np.asarray(code, np.uint32).tobytes().hex()
+
+
+def encode_announcements(codes, rankings, announcing=None
+                         ) -> Tuple[Dict[int, Dict[str, str]],
+                                    Dict[int, List[int]]]:
+    """The ledger entries of one reselection, from host arrays:
+    `announcements[i]` = a_i = {"lsh": code hex, "commit": SHA-256 of
+    the ranking} and `reveals[i]` = the ranking as ints, for every
+    client i, or only where the (M,) bool `announcing` is set."""
+    ids = [i for i in range(len(codes))
+           if announcing is None or announcing[i]]
+    announcements = {i: {"lsh": lsh_code_hex(codes[i]),
+                         "commit": sha256_commit(rankings[i])}
+                     for i in ids}
+    reveals = {i: rankings[i].tolist() for i in ids}
+    return announcements, reveals
 
 
 def save_chain(path: str, chain: Blockchain) -> str:

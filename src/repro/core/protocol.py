@@ -13,10 +13,12 @@ phases instead of forking a monolith (DESIGN.md §7):
                   (Alg. 1 l.19, step 6b)
   announce_phase  new LSH codes, rankings, commitments (step 7)
 
-`wpfed_program` composes them into a `core.rounds.RoundProgram`: the
-global round (all four phases — one federation iteration for all M
-clients) plus the gossip epoch (exchange + update against the cached
-`SelectResult`, DESIGN.md §8). Each phase call runs under a
+`wpfed_global_round` (all four phases — one federation iteration for
+all M clients) and `wpfed_gossip_round` (exchange + update against the
+cached `SelectResult`, DESIGN.md §8) are the one composition of the
+phases: `wpfed_program` wraps them as a `core.rounds.RoundProgram`,
+and the service's program (`service.driver`) calls them with its
+membership masks. Each phase call runs under a
 `jax.named_scope` of its short name (`repro.spans.PHASES`), which
 names its ops in the compiled program and nothing else. `make_wpfed_round` is the classic sync
 adapter over that program. Client models are homogeneous pytrees
@@ -25,6 +27,7 @@ across the mesh for TPU-scale runs.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Callable, Dict, NamedTuple, Tuple
 
@@ -343,54 +346,71 @@ def _round_metrics(sel: SelectResult, exch: ExchangeResult, train_metrics,
     }
 
 
+def wpfed_global_round(apply_fn: Callable, optimizer: Optimizer,
+                       fed: FedConfig, state: FedState,
+                       data: Dict[str, jnp.ndarray], *, active=None,
+                       score_scale=None
+                       ) -> Tuple[FedState, SelectResult, Dict]:
+    """Algorithm 1 verbatim: all four phases, each under its named
+    scope; returns (state, the round's `SelectResult`, metrics).
+
+    The service's optional membership masks (DESIGN.md §13): `active`
+    for `select_phase` and as `update_phase`'s `participate`,
+    `score_scale` for `select_phase`. The returned state carries every
+    client's fresh announcement; the service keeps only the active
+    ones. Without masks this is the classic sync round."""
+    rng, rng_sel, rng_upd = jax.random.split(state.rng, 3)
+
+    with jax.named_scope("select"):
+        sel = select_phase(state, fed, rng=rng_sel, active=active,
+                           score_scale=score_scale)
+    with jax.named_scope("exchange"):
+        exch = exchange_phase(apply_fn, fed, state.params, data, sel)
+    with jax.named_scope("update"):
+        params, opt_state, train_metrics = update_phase(
+            apply_fn, optimizer, fed, state.params, state.opt_state,
+            data, exch, rng_upd, participate=active)
+    with jax.named_scope("announce"):
+        ann = announce_phase(fed, params, sel, exch, state.round)
+
+    metrics = _round_metrics(sel, exch, train_metrics, state.round)
+    new_state = FedState(params, opt_state, ann.codes, ann.rankings,
+                         ann.commitments, rng, state.round + 1)
+    return new_state, sel, metrics
+
+
+def wpfed_gossip_round(apply_fn: Callable, optimizer: Optimizer,
+                       fed: FedConfig, state: FedState,
+                       data: Dict[str, jnp.ndarray], sel: SelectResult,
+                       *, participate=None
+                       ) -> Tuple[FedState, SelectResult, Dict]:
+    """The gossip epoch: exchange + update against the cached `sel`,
+    codes / rankings / commitments frozen. `participate` (M,) bool
+    freezes the params of the clients it leaves out."""
+    rng, rng_upd = jax.random.split(state.rng)
+    with jax.named_scope("exchange"):
+        exch = exchange_phase(apply_fn, fed, state.params, data, sel)
+    with jax.named_scope("update"):
+        params, opt_state, train_metrics = update_phase(
+            apply_fn, optimizer, fed, state.params, state.opt_state,
+            data, exch, rng_upd, participate=participate)
+    metrics = _round_metrics(sel, exch, train_metrics, state.round)
+    new_state = state._replace(params=params, opt_state=opt_state,
+                               rng=rng, round=state.round + 1)
+    return new_state, sel, metrics
+
+
 def wpfed_program(apply_fn: Callable, optimizer: Optimizer,
                   fed: FedConfig) -> RoundProgram:
-    """WPFed as a round program (DESIGN.md §8).
-
-    global_round is Algorithm 1 verbatim — all four phases; its cache
-    is the round's `SelectResult`. gossip_round is the cheap epoch
-    between reselections: exchange + update against the CACHED
-    selection, with codes / rankings / commitments frozen (no
-    announce_phase, no LSH re-code), so a reselection period costs one
-    global round plus G-1 exchange/update epochs.
-    """
-
-    def global_round(state: FedState, data: Dict[str, jnp.ndarray]
-                     ) -> Tuple[FedState, SelectResult, Dict]:
-        rng, rng_sel, rng_upd = jax.random.split(state.rng, 3)
-
-        with jax.named_scope("select"):
-            sel = select_phase(state, fed, rng=rng_sel)
-        with jax.named_scope("exchange"):
-            exch = exchange_phase(apply_fn, fed, state.params, data, sel)
-        with jax.named_scope("update"):
-            params, opt_state, train_metrics = update_phase(
-                apply_fn, optimizer, fed, state.params, state.opt_state,
-                data, exch, rng_upd)
-        with jax.named_scope("announce"):
-            ann = announce_phase(fed, params, sel, exch, state.round)
-
-        metrics = _round_metrics(sel, exch, train_metrics, state.round)
-        new_state = FedState(params, opt_state, ann.codes, ann.rankings,
-                             ann.commitments, rng, state.round + 1)
-        return new_state, sel, metrics
-
-    def gossip_round(state: FedState, data: Dict[str, jnp.ndarray],
-                     sel: SelectResult
-                     ) -> Tuple[FedState, SelectResult, Dict]:
-        rng, rng_upd = jax.random.split(state.rng)
-        with jax.named_scope("exchange"):
-            exch = exchange_phase(apply_fn, fed, state.params, data, sel)
-        with jax.named_scope("update"):
-            params, opt_state, train_metrics = update_phase(
-                apply_fn, optimizer, fed, state.params, state.opt_state,
-                data, exch, rng_upd)
-        metrics = _round_metrics(sel, exch, train_metrics, state.round)
-        new_state = state._replace(params=params, opt_state=opt_state,
-                                   rng=rng, round=state.round + 1)
-        return new_state, sel, metrics
-
-    return RoundProgram("wpfed", global_round, gossip_round)
+    """WPFed as a round program (DESIGN.md §8): the global round's
+    cache is its `SelectResult`, and the gossip epochs between
+    reselections skip announce_phase and the LSH re-code, so a
+    reselection period costs one global round plus G-1
+    exchange/update epochs."""
+    return RoundProgram(
+        "wpfed",
+        functools.partial(wpfed_global_round, apply_fn, optimizer, fed),
+        functools.partial(wpfed_gossip_round, apply_fn, optimizer, fed))
 
 
 def make_wpfed_round(apply_fn: Callable, optimizer: Optimizer,

@@ -31,7 +31,7 @@ from repro.core import (evaluate, init_state, instrument_program,
                         make_segment_fn, resolve_schedule, resolve_threat,
                         run_rounds, wpfed_program)
 from repro.core.adversary import THREATS
-from repro.core.chain import Blockchain, lsh_code_hex, sha256_commit
+from repro.core.chain import Blockchain, encode_announcements
 from repro.data import DATASETS
 from repro.models import apply_client_model, init_client_model
 from repro.optim import adam
@@ -55,11 +55,8 @@ def chain_publisher(chain: Blockchain, num_clients: int):
             codes = np.asarray(state.codes)
             rankings = np.asarray(state.rankings)
             spans.count(spans.HOST_PULLS, 2)
-            ann = {i: {"lsh": lsh_code_hex(codes[i]),
-                       "commit": sha256_commit(rankings[i])}
-                   for i in range(num_clients)}
-            reveals = {i: [int(x) for x in rankings[i]]
-                       for i in range(num_clients)}
+            ann, reveals = encode_announcements(codes[:num_clients],
+                                                rankings[:num_clients])
             chain.publish_round(round_idx + 1, ann, reveals=reveals)
 
     return publish

@@ -14,8 +14,8 @@ An io_callback / host-loop hybrid over the `core.rounds` engine:
     through `checkpoint.store` (with retention) so a killed service
     resumes bit-exact (`resume_service`).
 
-The service round program wraps the WPFed phases with the membership
-masks:
+The service round program runs `core.protocol`'s global round and
+gossip epoch under the membership masks:
 
   global round   §3.6 verification restricted to active reporters,
                  Eq. 8 scores discounted by exp(-lambda * code_age)
@@ -58,8 +58,7 @@ from repro.checkpoint import store
 from repro.configs.paper_models import FedConfig
 from repro.core.chain import Blockchain, save_chain
 from repro.core.faults import FaultPlan, fault_scalars
-from repro.core.protocol import (FedState, _round_metrics, announce_phase,
-                                 exchange_phase, select_phase, update_phase)
+from repro.core.protocol import wpfed_global_round, wpfed_gossip_round
 from repro.core.rounds import (RoundProgram, extract_history,
                                make_segment_fn, run_period_program)
 from repro.service.membership import (ChurnEvent, ServiceConfig,
@@ -89,22 +88,24 @@ class CrashInjected(RuntimeError):
 # ---------------------------------------------------------------------------
 # the service round program
 # ---------------------------------------------------------------------------
-def _service_metrics(sel, exch, train_metrics, state: ServiceState,
+def _service_metrics(metrics, state: ServiceState,
                      participate) -> Dict[str, jnp.ndarray]:
     """The engine's per-round metrics plus the membership telemetry.
     Identical structure in the global round and every gossip epoch so
     a period stacks under lax.scan."""
-    base = _round_metrics(sel, exch, train_metrics, state.fed.round)
-    base["active_frac"] = jnp.mean(state.active.astype(jnp.float32))
-    base["participation_frac"] = jnp.mean(
+    metrics["active_frac"] = jnp.mean(state.active.astype(jnp.float32))
+    metrics["participation_frac"] = jnp.mean(
         participate.astype(jnp.float32))
-    base["mean_code_age"] = jnp.mean(state.code_age.astype(jnp.float32))
-    return base
+    metrics["mean_code_age"] = jnp.mean(
+        state.code_age.astype(jnp.float32))
+    return metrics
 
 
 def service_program(apply_fn: Callable, optimizer, fed: FedConfig,
                     svc: ServiceConfig) -> RoundProgram:
-    """WPFed as a churn-tolerant service program over ServiceState.
+    """WPFed as a churn-tolerant service program over ServiceState:
+    `core.protocol`'s global round and gossip epoch under the
+    membership masks.
 
     The decision here is churn-as-masking (DESIGN.md §13): departed
     clients still occupy their padded slot and their (frozen) params
@@ -127,56 +128,36 @@ def service_program(apply_fn: Callable, optimizer, fed: FedConfig,
 
     def global_round(state: ServiceState, data
                      ) -> Tuple[ServiceState, Any, Dict]:
-        st = state.fed
-        rng, rng_sel, rng_upd = jax.random.split(st.rng, 3)
-        with jax.named_scope("select"):
-            sel = select_phase(
-                st, fed, rng=rng_sel, active=state.active,
-                score_scale=staleness_discount(state.code_age,
-                                               svc.staleness_lambda))
-        with jax.named_scope("exchange"):
-            exch = exchange_phase(apply_fn, fed, st.params, data, sel)
-        with jax.named_scope("update"):
-            params, opt_state, train_metrics = update_phase(
-                apply_fn, optimizer, fed, st.params, st.opt_state, data,
-                exch, rng_upd, participate=state.active)
-        with jax.named_scope("announce"):
-            ann = announce_phase(fed, params, sel, exch, st.round)
-        a = state.active
+        st, a = state.fed, state.active
+        new_fed, sel, metrics = wpfed_global_round(
+            apply_fn, optimizer, fed, st, data, active=a,
+            score_scale=staleness_discount(state.code_age,
+                                           svc.staleness_lambda))
         # these merged fields are what transport.collect reads onto the
         # host ledger and what checkpoints as chain.json — the service's
         # disclosure point (repro.analysis.taint verifies it)
         codes, rankings, commitments = sink("ledger-publish", (
-            jnp.where(a[:, None], ann.codes, st.codes),
-            jnp.where(a[:, None], ann.rankings, st.rankings),
-            jnp.where(a, ann.commitments, st.commitments)))
-        new_fed = FedState(params, opt_state, codes, rankings,
-                           commitments, rng, st.round + 1)
-        metrics = _service_metrics(sel, exch, train_metrics, state, a)
+            jnp.where(a[:, None], new_fed.codes, st.codes),
+            jnp.where(a[:, None], new_fed.rankings, st.rankings),
+            jnp.where(a, new_fed.commitments, st.commitments)))
+        new_fed = new_fed._replace(codes=codes, rankings=rankings,
+                                   commitments=commitments)
         new_state = ServiceState(
             new_fed, a, jnp.where(a, 0, state.code_age + 1),
             state.gossip_count, jnp.asarray(st.round, jnp.int32))
-        return new_state, sel, metrics
+        return new_state, sel, _service_metrics(metrics, state, a)
 
     def gossip_round(state: ServiceState, data, sel
                      ) -> Tuple[ServiceState, Any, Dict]:
-        st = state.fed
-        rng, rng_upd = jax.random.split(st.rng)
         # 0-based gossip epoch within the period (round already
         # advanced past the period's global round)
-        epoch = st.round - state.period_start - 1
+        epoch = state.fed.round - state.period_start - 1
         part = participation_mask(state, epoch)
-        with jax.named_scope("exchange"):
-            exch = exchange_phase(apply_fn, fed, st.params, data, sel)
-        with jax.named_scope("update"):
-            params, opt_state, train_metrics = update_phase(
-                apply_fn, optimizer, fed, st.params, st.opt_state, data,
-                exch, rng_upd, participate=part)
-        metrics = _service_metrics(sel, exch, train_metrics, state, part)
-        new_state = state._replace(fed=st._replace(
-            params=params, opt_state=opt_state, rng=rng,
-            round=st.round + 1))
-        return new_state, sel, metrics
+        new_fed, sel, metrics = wpfed_gossip_round(
+            apply_fn, optimizer, fed, state.fed, data, sel,
+            participate=part)
+        return (state._replace(fed=new_fed), sel,
+                _service_metrics(metrics, state, part))
 
     return RoundProgram("wpfed-service", global_round, gossip_round)
 

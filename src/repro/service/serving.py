@@ -134,7 +134,7 @@ class PersonalizedServer:
         self._params = params
 
     def throughput(self):  # analysis: host-ok (telemetry summarization)
-        """Summary stats for BENCH_service.json."""
+        """Summary stats of the requests served so far."""
         lat = self.stats["latency_s"]
         total = max(self.stats["total_s"], 1e-9)
         return {

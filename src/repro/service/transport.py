@@ -23,9 +23,9 @@ source of truth with the driver's degraded-round bookkeeping: both
 read `core.faults.period_faults`, so the counters streamed through the
 metric tap and the faults the transport actually applies can never
 diverge. With `plan=None` the transport is the production fault-free
-path — same checksums, same retry envelope, zero injected faults —
-and `benchmarks/service_bench.py` pins its overhead against the bare
-publisher.
+path — same checksums, same retry envelope, zero injected faults.
+Both this transport and the sync path's `launch.fed.chain_publisher`
+encode their entries with `core.chain.encode_announcements`.
 
 Everything here is host-side by construction: the transport IS the
 device->host disclosure boundary (the in-graph side of it is the
@@ -45,8 +45,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro import spans
-from repro.core.chain import (Block, Blockchain, load_chain, lsh_code_hex,
-                              save_chain, sha256_commit)
+from repro.core.chain import (Block, Blockchain, encode_announcements,
+                              load_chain, save_chain)
 from repro.core.faults import (FaultPlan, FaultTrace, PeriodFaults,
                                fault_u01, leading_failures, period_faults)
 
@@ -183,13 +183,11 @@ class BulletinTransport:
             pf = self.period_faults(period, m)
             failed = np.zeros(m, bool)
             delayed = np.zeros(m, bool)
+            encoded, revealed = encode_announcements(codes, rankings,
+                                                     announcing)
             announcements: Dict[int, Dict[str, str]] = {}
             reveals: Dict[int, List[int]] = {}
-            for i in range(m):
-                if not announcing[i]:
-                    continue
-                entry = {"lsh": lsh_code_hex(codes[i]),
-                         "commit": sha256_commit(rankings[i])}
+            for i, entry in encoded.items():
                 entry["sum"] = announcement_checksum(entry)
                 if pf is not None:
                     if pf.drop[i]:
@@ -215,7 +213,7 @@ class BulletinTransport:
                         # the second, byte-identical copy dedupes to nothing
                         self.trace.record(period, "duplicate", i)
                 announcements[i] = entry
-                reveals[i] = [int(x) for x in rankings[i]]
+                reveals[i] = revealed[i]
             return announcements, reveals, failed, delayed
 
     def _with_retry(self, period: int, kind: str, stream: int,

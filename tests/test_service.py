@@ -140,6 +140,67 @@ def test_leave_freezes_update_and_announce(svc_state):
     assert int(new_state.code_age[0]) == 0
 
 
+@pytest.mark.parametrize("kind", ["global", "gossip"])
+def test_full_membership_service_round_equals_sync_round(svc_state, kind):
+    """Every client active, code_age 0 and full gossip budgets: the
+    service round is the sync round. Its FedState and the metrics both
+    programs report are bitwise equal to `wpfed_program`'s, in the
+    global round and in a gossip epoch."""
+    from repro.core.protocol import wpfed_program
+    args = (svc_state["apply_fn"], svc_state["opt"], svc_state["fed"])
+    sync = wpfed_program(*args)
+    service = service_program(*args, svc_state["svc"])
+    data = svc_state["data"]
+    # one sync round first, so the compared round ranks real evidence
+    warm = jax.jit(sync.global_round)(svc_state["state"].fed, data)[0]
+    st = init_service_state(warm, svc_state["svc"])
+    sync_st, sync_sel, sync_m = jax.jit(sync.global_round)(st.fed, data)
+    svc_st, svc_sel, svc_m = jax.jit(service.global_round)(st, data)
+    if kind == "gossip":
+        sync_st, _, sync_m = jax.jit(sync.gossip_round)(
+            sync_st, data, sync_sel)
+        svc_st, _, svc_m = jax.jit(service.gossip_round)(
+            svc_st, data, svc_sel)
+    for a, b in zip(jax.tree.leaves(sync_st), jax.tree.leaves(svc_st.fed)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert set(sync_m) <= set(svc_m)
+    for k in sync_m:
+        assert np.array_equal(np.asarray(sync_m[k]), np.asarray(svc_m[k])), k
+
+
+def test_sync_and_service_ledger_entries_agree(svc_state):
+    """One encoder for both ledgers: for one state, the sync
+    publisher's block entries are the transport's with "sum" removed,
+    each "sum" is the checksum of the rest, and a client outside the
+    announcing mask is left out of both announcements and reveals."""
+    from repro.launch.fed import chain_publisher
+    from repro.service.transport import (BulletinTransport,
+                                         announcement_checksum)
+    program = service_program(svc_state["apply_fn"], svc_state["opt"],
+                              svc_state["fed"], svc_state["svc"])
+    st, _, _ = jax.jit(program.global_round)(svc_state["state"],
+                                             svc_state["data"])
+    m = svc_state["fed"].num_clients
+    chain = Blockchain()
+    chain_publisher(chain, m)(0, st.fed)
+    block = chain.blocks[-1].payload
+    ann, reveals, failed, delayed = BulletinTransport(Blockchain()).collect(
+        0, np.ones(m, bool), st)
+    assert not failed.any() and not delayed.any()
+    assert {str(i): {k: v for k, v in e.items() if k != "sum"}
+            for i, e in ann.items()} == block["announcements"]
+    assert {str(i): r for i, r in reveals.items()} == block["reveals"]
+    for e in ann.values():
+        assert e["sum"] == announcement_checksum(e)
+    some = np.ones(m, bool)
+    some[2] = False
+    ann2, reveals2, _, _ = BulletinTransport(Blockchain()).collect(
+        0, some, st)
+    assert sorted(ann2) == sorted(reveals2) == [i for i in range(m)
+                                                if i != 2]
+    assert all(ann2[i] == ann[i] for i in ann2)
+
+
 # ---------------------------------------------------------------------------
 # membership mechanics
 # ---------------------------------------------------------------------------
