@@ -139,9 +139,9 @@ def exchange_phase(apply_fn: Callable, fed: FedConfig, params,
     verification in one pass — core.exchange, DESIGN.md §7).
 
     ref_mode="personal": neighbors answer each client's OWN reference
-    set, so the logit web needs M*N forwards over gathered neighbor
-    params (the collective-friendly form of the paper's point-to-point
-    sends, DESIGN.md §3).
+    set, so the logit web needs M*N forwards and the clients' own M,
+    run a few (client, neighbor) pairs a step (`personal_ref_logits`)
+    with no gather of neighbor parameters up front.
 
     ref_mode="public": every client evaluates the SHARED reference set
     (row 0 of data["x_ref"] — the abstract's public reference dataset)
@@ -161,12 +161,9 @@ def exchange_phase(apply_fn: Callable, fed: FedConfig, params,
         y_ref = jnp.broadcast_to(data["y_ref"][0][None],
                                  (m,) + data["y_ref"].shape[1:])
     else:
-        nb_params = jax.tree.map(lambda p: p[sel.ids], params)  # (M, N, ...)
-        y_web = public_ref_logits(
-            jax.vmap(                                   # over clients i
-                jax.vmap(apply_fn, in_axes=(0, None))   # over neighbors j
-            )(nb_params, data["x_ref"]))                # (M, N, R, C)
-        own_ref = jax.vmap(apply_fn)(params, data["x_ref"])     # (M, R, C)
+        own_ref, web = personal_ref_logits(apply_fn, params,
+                                           data["x_ref"], sel.ids)
+        y_web = public_ref_logits(web)                  # (M, N, R, C)
         y_ref = data["y_ref"]
     return all_in_one_exchange(own_ref, y_web, y_ref, sel.sel_mask, fed)
 
@@ -256,36 +253,45 @@ def _local_update(apply_fn, optimizer, fed: FedConfig, params, opt_state,
                                "ref_loss": l_refs[-1]}
 
 
-# the lanes a chunk of clients fills in a conv's channel axis, twice
-# the vector unit's 128: on a v5e the TCN's width-32 update ran
-# fastest at 8 clients a step (PERF.md §6: 4 and 16 were slower)
+# the lanes a chunk fills in a conv's channel axis, twice the vector
+# unit's 128: on a v5e the TCN's width-32 update ran fastest at 8
+# clients a step, and the CNN's exchange within 2% of its fastest at 8
+# pairs (PERF.md §6)
 CHUNK_LANES = 256
 # counted each time the update traces: the chunk width it chose
 UPDATE_CHUNK = "update.chunk"
 
 
-def update_chunk(backend: str, m: int, shapes) -> int:
-    """How many clients one step of the local update runs at once.
+def lane_chunk(backend: str, count: int, shapes) -> int:
+    """How many of `count` models (clients, or (client, neighbor)
+    pairs) one ``lax.map`` step runs at once: the fewest whose convs
+    fill `CHUNK_LANES`, capped at `count`.
 
     `shapes` are one client's parameter leaf shapes. Under ``vmap`` a
     convolution over per-client weights folds the clients into its
     channel axis (``feature_group_count``), so k clients of a conv of
     width w fill k*w lanes where one client pads w to 128. The width
     is the narrowest output channel count of the conv kernels (leaves
-    of rank >= 3: window..., in, out), and k the fewest clients that
-    fill `CHUNK_LANES`, capped at M.
-
-    1 where the conv kernels hold less than half of the parameters:
-    dense layers batch as separate matmuls and gain nothing, and on a
-    v5e chunking a client whose parameters sit in a 3136x128 dense
-    layer slowed the period program by 11% (PERF.md §6). 1 on the
-    CPU too: XLA-CPU runs grouped-conv gradients ~25x slower than
-    plain ones (measured)."""
+    of rank >= 3: window..., in, out). 1 without conv kernels, and on
+    the CPU: XLA-CPU runs grouped-conv gradients ~25x slower than
+    plain ones, and grouped forwards no faster (measured)."""
     conv = [s for s in shapes if len(s) >= 3]
-    if (backend == "cpu" or 2 * sum(map(math.prod, conv))
-            < sum(map(math.prod, shapes))):
+    if backend == "cpu" or not conv:
         return 1
-    return min(m, -(-CHUNK_LANES // min(s[-1] for s in conv)))
+    return min(count, -(-CHUNK_LANES // min(s[-1] for s in conv)))
+
+
+def update_chunk(backend: str, m: int, shapes) -> int:
+    """How many clients one step of the local update runs at once:
+    `lane_chunk` of the M clients, but 1 where the conv kernels hold
+    less than half of the parameters. Dense layers batch as separate
+    matmuls and gain nothing, and on a v5e chunking the update of a
+    client whose parameters sit in a 3136x128 dense layer slowed the
+    period program by 11% (PERF.md §6)."""
+    conv = [s for s in shapes if len(s) >= 3]
+    if 2 * sum(map(math.prod, conv)) < sum(map(math.prod, shapes)):
+        return 1
+    return lane_chunk(backend, m, shapes)
 
 
 def _chunked_local_update(apply_fn, optimizer, fed: FedConfig, params,
@@ -316,6 +322,52 @@ def batched_local_update(apply_fn, optimizer, fed: FedConfig, params,
     return _chunked_local_update(apply_fn, optimizer, fed, params,
                                  opt_state, data_per, target_ref,
                                  has_target, keys, width)
+
+
+# ---------------------------------------------------------------------------
+# the personal exchange's forwards
+# ---------------------------------------------------------------------------
+# counted each time the personal exchange traces: the chunk width it chose
+EXCHANGE_CHUNK = "exchange.chunk"
+
+
+def _mapped_ref_logits(apply_fn, params, x_ref, ids, width: int):
+    """Each client's own logits and its neighbors' on its reference set:
+    the (M, N+1) pairs (i, i), (i, ids[i, 0]), ..., (i, ids[i, N-1]),
+    `width` pairs a step under ``lax.map`` (a remainder chunk where
+    `width` does not divide M*(N+1)); 1 is the plain one-pair map. A
+    step indexes the stacked `params` and `x_ref` for its own pairs
+    only, the way ``p[ids]`` indexes: a negative id wraps once, then
+    an id is clamped into [0, M). Returns own (M, R, C) and the web
+    (M, N, R, C)."""
+    m, n = ids.shape
+    client = jnp.arange(m, dtype=ids.dtype)
+    model = jnp.concatenate([client[:, None], ids], axis=1)      # (M, N+1)
+    owner = jnp.broadcast_to(client[:, None], model.shape)
+
+    def one(pair):
+        i, j = pair
+        return apply_fn(jax.tree.map(lambda p: p[j], params), x_ref[i])
+
+    out = jax.lax.map(one, (owner.reshape(-1), model.reshape(-1)),
+                      batch_size=width if width > 1 else None)
+    out = out.reshape((m, n + 1) + out.shape[1:])
+    return out[:, 0], out[:, 1:]
+
+
+def personal_ref_logits(apply_fn, params, x_ref, ids):
+    """`_mapped_ref_logits` at the width `lane_chunk` picks from the
+    backend, the M*(N+1) pairs and one client's parameter shapes; the
+    trace counts it as `EXCHANGE_CHUNK`. Forwards alone, so unlike the
+    update it chunks the dense-heavy CNN too: on a v5e its m128
+    exchange took 15.6 ms at 8 pairs a step, 18.5 at 1 and 19.9 at
+    one client's 13 (PERF.md §6)."""
+    leaves = jax.tree.leaves(params)
+    width = lane_chunk(jax.default_backend(),
+                       ids.shape[0] * (ids.shape[1] + 1),
+                       [x.shape[1:] for x in leaves])
+    spans.count(EXCHANGE_CHUNK, width)
+    return _mapped_ref_logits(apply_fn, params, x_ref, ids, width)
 
 
 # ---------------------------------------------------------------------------

@@ -28,7 +28,7 @@ from repro import spans
 from repro.core import Schedule, evaluate, init_state, run_rounds
 from repro.core import wpfed_program
 from repro.core.chain import Blockchain
-from repro.core.protocol import UPDATE_CHUNK
+from repro.core.protocol import EXCHANGE_CHUNK, UPDATE_CHUNK
 from repro.launch.fed import chain_publisher
 from repro.service import ServiceConfig, init_service_state, run_service
 
@@ -157,13 +157,14 @@ def test_compile_is_recorded_once_per_segment_length(fed4):
              if s["name"] in ("period.compile", "period.dispatch")]
     assert calls == ["period.compile", "period.dispatch", "period.compile"]
     assert _counter("period.traces") - traces == 2
-    # each trace also counts the local update's chunk width (1 on the
-    # CPU) once per update it traces: the global round and the gossip
-    # epoch's scan body, then the tail period's global round alone
+    # each trace also counts the local update's and the personal
+    # exchange's chunk widths (1 on the CPU) once per phase it traces:
+    # the global round and the gossip epoch's scan body, then the tail
+    # period's global round alone
     assert [s["counts"] for s in recorded
             if s["name"] == "period.compile"] == [
-        {"period.traces": 1, UPDATE_CHUNK: 2},
-        {"period.traces": 1, UPDATE_CHUNK: 1}]
+        {"period.traces": 1, UPDATE_CHUNK: 2, EXCHANGE_CHUNK: 2},
+        {"period.traces": 1, UPDATE_CHUNK: 1, EXCHANGE_CHUNK: 1}]
     # the history pulls 8 arrays a period, of 3, 3 and 1 rounds
     assert [s["counts"] for s in recorded
             if s["name"] == "period.history"] == [{spans.HOST_PULLS: 8}] * 3

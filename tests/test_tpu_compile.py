@@ -11,7 +11,9 @@ and the paper's exchange shape (M=16 after padding, N=9, R=64, C=10).
 The local update's chunked form (`protocol._chunked_local_update` at
 the width `update_chunk` gives a TPU) compiles too, at the benchmark's
 m1024 TCN shapes and at M=10 (a remainder chunk), within a few GB of
-scratch memory.
+scratch memory. So does the personal exchange's mapped forwards
+(`protocol._mapped_ref_logits` at the width `lane_chunk` gives a
+TPU), at the benchmark's m128 and m10 CNN shapes.
 
 The topology is described inside a module fixture, never at import:
 only one process at a time may load the TPU compiler's library.
@@ -200,3 +202,32 @@ def test_chunked_update_compiles_for_v5e(case, sds):
         sds((m, ref_rows, mcfg.num_classes), jnp.float32),
         sds((m,), jnp.bool_), sds(keys.shape, keys.dtype)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 4e9, case
+
+
+# (model, M, N, reference rows) of the benchmark's personal CNN cells
+EXCHANGE_CASES = {"mnist_cnn-m128": ("mnist_cnn", 128, 12, 64),
+                  "mnist_cnn-m10": ("mnist_cnn", 10, 9, 64)}
+
+
+@pytest.mark.parametrize("case", list(EXCHANGE_CASES))
+def test_mapped_exchange_compiles_for_v5e(case, sds):
+    import functools
+    from repro.configs import paper_models
+    from repro.core import protocol
+    from repro.models import apply_client_model, init_client_model
+    kind, m, n, ref_rows = EXCHANGE_CASES[case]
+    mcfg = getattr(paper_models, kind)()
+    one = jax.eval_shape(functools.partial(init_client_model, mcfg),
+                         jax.random.PRNGKey(0))
+    width = protocol.lane_chunk(
+        "tpu", m * (n + 1), [x.shape for x in jax.tree.leaves(one)])
+    assert width > 1, case
+    compiled = jax.jit(functools.partial(
+        protocol._mapped_ref_logits,
+        functools.partial(apply_client_model, mcfg), width=width)).lower(
+        jax.tree.map(lambda x: sds((m,) + x.shape, x.dtype), one),
+        sds((m, ref_rows) + mcfg.input_shape, jnp.float32),
+        sds((m, n), jnp.int32)).compile()
+    # no pair's parameters are gathered up front: the double vmap over
+    # gathered parameters took 4.9 GB of scratch at m128
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9, case
